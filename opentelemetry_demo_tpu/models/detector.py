@@ -195,8 +195,8 @@ def report_pack(report: DetectorReport) -> jnp.ndarray:
 
     A pytree ``device_get`` pays one transfer per leaf; packing on
     device makes the harvest a single transfer (the difference matters
-    most where per-transfer latency dominates bandwidth — remote or
-    tunneled device topologies). :func:`report_unpack` restores the
+    most where per-transfer latency dominates bandwidth).
+    :func:`report_unpack` restores the
     structure host-side. Fields are handled by NAME (bool fields via
     ``_BOOL_REPORT_FIELDS``) so field order/appends can't silently
     scramble the layout."""

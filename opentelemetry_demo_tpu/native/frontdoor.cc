@@ -2,9 +2,10 @@
 // acceptor (ISSUE 19 tentpole; ROADMAP item 3's "front end is the
 // wall" seam).
 //
-// BENCH_r06 showed the pooled decode engine flat at ~6.1-6.2M spans/s
-// across 1-4 workers because every byte still traversed the Python
-// http.server receiver before on_payload could hand it to the pool.
+// A one-core CPU runner showed the pooled decode engine flat at
+// ~6.1-6.2M spans/s across 1-4 workers because every byte traversed
+// the Python http.server receiver before on_payload could hand it to
+// the pool.
 // This translation unit owns the whole socket→scan path natively:
 //
 //   accept → HTTP/1.1 framing (Content-Length, 413 oversize cap,
@@ -18,7 +19,7 @@
 // (one GIL-released otd_fd_next call per batch) and the decode scans
 // the buffers in place via the existing otd_decode_otlp_many pointer
 // ABI. Python keeps only the control plane — the 429/413/400 verdict
-// taxonomy decisions that need pipeline state (saturation hints, the
+// vocabulary decisions that need pipeline state (saturation hints, the
 // DecodeTicket per-request decode verdicts), /healthz wiring, metrics
 // and graceful drain — exactly the split runtime/otlp.py documents.
 //

@@ -259,14 +259,10 @@ def _hist_mxu(flat: jnp.ndarray, n_bins: int) -> jnp.ndarray:
     sentinel_count = jnp.sum((flat >= n_bins).astype(jnp.int32))
     flat = jnp.minimum(flat, n_bins - 1)
     n_hi = n_bins // 256
-    try:
-        # Propagate the keys' varying-manual-axes under shard_map; on
-        # older jax (no typeof/vma) a plain struct is exactly right.
-        out_struct = jax.ShapeDtypeStruct(
-            (n_hi, 256), jnp.int32, vma=jax.typeof(flat).vma
-        )
-    except (AttributeError, TypeError):
-        out_struct = jax.ShapeDtypeStruct((n_hi, 256), jnp.int32)
+    # Propagate the keys' varying-manual-axes under shard_map.
+    out_struct = jax.ShapeDtypeStruct(
+        (n_hi, 256), jnp.int32, vma=jax.typeof(flat).vma
+    )
 
     # Keys as ONE [1, n] row, blocked along the lane axis: the block's
     # leading dim (1) equals the array's, satisfying the Pallas TPU

@@ -188,6 +188,7 @@ import time
 
 from ..models.detector import AnomalyDetector, DetectorConfig
 from ..telemetry import metrics as tele_metrics
+from ..utils.compile_cache import enable_compile_cache
 from ..utils.config import (
     ConfigError,
     autoscale_config,
@@ -236,6 +237,16 @@ def _package_version() -> str:
         return str(__version__)
     except Exception:  # noqa: BLE001 — a label must never fail boot
         return "unknown"
+
+
+def _device_identity() -> tuple[str, str]:
+    """(platform, device_kind) of the device the detector runs on, as
+    JAX reports it — the boot line and build_info say which device
+    served, never assumed."""
+    import jax
+
+    dev = jax.devices()[0]
+    return dev.platform, dev.device_kind
 
 
 def _jax_version() -> str:
@@ -485,15 +496,19 @@ class DetectorDaemon:
         # start_ts rides /healthz (restart forensics pair with bundle
         # timestamps through these two surfaces).
         self._start_ts = time.time()
+        self.device_platform, self.device_kind = _device_identity()
         self.registry.gauge_set(
             tele_metrics.ANOMALY_BUILD_INFO, 1.0,
             version=_package_version(),
             frame_version=str(frame_fmt.FRAME_VERSION),
             jax=_jax_version(),
+            platform=self.device_platform,
+            device_kind=self.device_kind,
         )
         self.registry.describe(
             tele_metrics.ANOMALY_BUILD_INFO,
-            "Constant 1 labelled with package/frame/jax versions",
+            "Constant 1 labelled with package/frame/jax versions and "
+            "the device platform/kind",
         )
         self.registry.describe(
             tele_metrics.ANOMALY_FLAG_TOTAL,
@@ -1000,8 +1015,8 @@ class DetectorDaemon:
             flags=flags,
             on_report=self._on_report,
             batch_size=self.batch_size,
-            # Remote/tunneled devices: readback RTT dominates — set an
-            # interval (and/or async) so dispatch never waits on fetch.
+            # Where readback dominates, set an interval (and/or async)
+            # so dispatch never waits on fetch.
             harvest_interval_s=float(dk["ANOMALY_HARVEST_INTERVAL"]),
             harvest_async=bool(int(dk["ANOMALY_HARVEST_ASYNC"])),
             # Adaptive width (on by default): bounds the report skip
@@ -1085,8 +1100,13 @@ class DetectorDaemon:
             lambda: (self.role, self._fence.epoch)
         )
         if self.pipeline.adaptive_batching:
+            self.registry.describe(
+                tele_metrics.ANOMALY_WIDTH_WARMUPS,
+                "Adaptive-width ladder precompiles at boot, by width "
+                "and outcome (ok|error)",
+            )
             threading.Thread(
-                target=self._warm_widths_quietly,
+                target=self._warm_ladder,
                 name="width-ladder-warmup", daemon=True,
             ).start()
         # Positional re-adoption, NOT name-by-name re-interning: a
@@ -1981,13 +2001,24 @@ class DetectorDaemon:
 
     # -- report → metrics ---------------------------------------------
 
-    def _warm_widths_quietly(self) -> None:
-        """Background ladder precompile; failure is non-fatal (the
-        controller would then pay one compile at escalation time)."""
-        try:
-            self.pipeline.warm_widths()
-        except Exception:  # noqa: BLE001 — warmup must never kill boot
-            pass
+    def _warm_ladder(self) -> None:
+        """Background ladder precompile. A width that fails is logged
+        with its error and counted (outcome="error"); the daemon stays
+        up and the controller would pay that compile again at
+        escalation time."""
+        for width in self.pipeline.ladder_widths():
+            try:
+                self.pipeline.warm_width(width)
+                outcome = "ok"
+            except Exception:  # noqa: BLE001 — logged + counted; boot survives a width
+                logging.getLogger(__name__).exception(
+                    "adaptive width %d failed to compile", width
+                )
+                outcome = "error"
+            self.registry.counter_add(
+                tele_metrics.ANOMALY_WIDTH_WARMUPS, 1.0,
+                width=str(width), outcome=outcome,
+            )
 
     # -- remediation wiring --------------------------------------------
 
@@ -3817,6 +3848,7 @@ def main() -> None:
     # SIGUSR1 dumps all stacks — the debugging handle for a wedged
     # daemon (kill -USR1 <pid>), matching Go services' SIGQUIT habit.
     faulthandler.register(signal.SIGUSR1, all_threads=True)
+    enable_compile_cache()
     daemon = DetectorDaemon()
     signal.signal(signal.SIGTERM, lambda *_: daemon.stop())
     signal.signal(signal.SIGINT, lambda *_: daemon.stop())
@@ -3838,7 +3870,8 @@ def main() -> None:
         print(
             f"anomaly-detector: otlp-http :{http_port} "
             f"otlp-grpc :{grpc_port} metrics :{d.exporter.port} "
-            f"repl :{repl_port} query :{query_port} role {d.role}",
+            f"repl :{repl_port} query :{query_port} role {d.role} "
+            f"device {d.device_platform} ({d.device_kind})",
             flush=True,
         )
 

@@ -11,7 +11,7 @@ Python, because it needs pipeline state:
   bodies go to the decode pool's POINTER path (``pool.submit`` of a
   zero-copy ctypes view — ``decode_otlp_many`` scans the native buffer
   in place), metrics/logs take the Python decoders at scrape cadence;
-- the verdict taxonomy, bit-compatible with ``runtime/otlp.py``'s
+- the verdict vocabulary, bit-compatible with ``runtime/otlp.py``'s
   receiver: pipeline saturation → 429 + integer Retry-After (rounded
   up), pool saturation → 429 + Retry-After: 1, a server-side flush
   failure → 500, and the per-request DECODE verdict carried by the
@@ -36,7 +36,7 @@ Python, because it needs pipeline state:
 Deliberately ABSENT from this module: ``http.server``,
 ``socketserver``, and any per-request Python object on the trace
 path — scripts/sanitycheck.py pins both (the zero-Python-HTTP
-monopoly), and tests/test_frontdoor.py proves the taxonomy against
+monopoly), and tests/test_frontdoor.py proves the vocabulary against
 the Python receiver on a shared corpus.
 """
 

@@ -9,9 +9,9 @@ Two measurements this repo never had, one module, one JSON line:
   and payload geometry. The front door pays real sockets and HTTP
   framing that the in-process number never does, so meeting the
   baseline means the native acceptor's framing is genuinely free
-  relative to decode — the claim BENCH_r06 said the Python receiver
-  could not make (pooled ingest flat at ~6.1M spans/s because the
-  front end, not decode, was the wall).
+  relative to decode — the claim the Python receiver could not make
+  (pooled ingest flat at ~6.1M spans/s on a one-core CPU runner,
+  because the front end, not decode, was the wall).
 
 - ``measure_million_key_soak`` — the repo's first scale-of-keys run:
   a synthetic shop-fleet generator drives ≥1M distinct

@@ -12,9 +12,14 @@ Two kernels live behind this module:
   Semantics pinned by tests/test_native_currency.py.
 
 Build-on-demand: each library is one translation unit compiled with
-``g++ -O3`` (~1 s, cached by mtime against its source). Environments
-without a compiler simply report ``available() == False`` and callers
-fall back to the pure-Python paths — same results, less throughput.
+``g++ -O3`` (~1 s). The output is named by a hash of the compiler, its
+flags and the source bytes, so a library built from other source or
+with other flags (a copy of ``_build/`` from elsewhere, a stale build)
+is never loaded. Environments without a compiler simply report
+``available() == False`` and callers fall back to the pure-Python
+paths — same results, less throughput. ``python -m
+opentelemetry_demo_tpu.runtime.native`` builds all of them (the images
+do this at build time).
 
 **GIL contract** (the ingest pool's scaling story): libraries load via
 ``ctypes.CDLL`` — NOT ``ctypes.PyDLL`` — so ctypes RELEASES the GIL
@@ -42,6 +47,7 @@ interpreter beyond the call returning sooner.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -85,28 +91,36 @@ class ColumnarOrders(NamedTuple):
     attr_crc: np.ndarray  # uint32[N] — CRC32 of first product id
 
 
-def _build(name: str) -> str | None:
-    """Compile native/<name>.cc if missing/stale; returns error string."""
-    src = os.path.join(_DIR, f"{name}.cc")
-    out = os.path.join(_DIR, "_build", f"libotd_{name}.so")
-    os.makedirs(os.path.dirname(out), exist_ok=True)
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+# ingest.cc's sharded decode_many spawns std::thread workers; -pthread
+# is required for that on Linux and harmless for the single-threaded
+# translation units sharing this build rule.
+_CXXFLAGS = (
+    "-O3", "-std=c++17", "-fPIC", "-Wall", "-Wextra", "-pthread", "-shared",
+)
+
+
+def _lib_path(name: str) -> str:
+    """Where native/<name>.cc's library lives for THIS source, compiler
+    and flags: the file name carries their hash."""
+    cxx = os.environ.get("CXX", "g++")
+    h = hashlib.sha256("\0".join((cxx,) + _CXXFLAGS).encode())
+    with open(os.path.join(_DIR, f"{name}.cc"), "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, "_build", f"libotd_{name}-{h.hexdigest()[:16]}.so")
+
+
+def _build(name: str, out: str) -> str | None:
+    """Compile native/<name>.cc to ``out`` unless it exists; returns an
+    error string on failure."""
+    if os.path.exists(out):
         return None
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    # Build beside, then rename: concurrent builders (test workers,
+    # several daemons) never load a half-written library.
+    tmp = f"{out}.{os.getpid()}.{threading.get_ident()}.tmp"
     cmd = [
-        os.environ.get("CXX", "g++"),
-        "-O3",
-        "-std=c++17",
-        "-fPIC",
-        "-Wall",
-        "-Wextra",
-        # ingest.cc's sharded decode_many spawns std::thread workers;
-        # -pthread is required for that on Linux and harmless for the
-        # single-threaded translation units sharing this build rule.
-        "-pthread",
-        "-shared",
-        "-o",
-        out,
-        src,
+        os.environ.get("CXX", "g++"), *_CXXFLAGS, "-o", tmp,
+        os.path.join(_DIR, f"{name}.cc"),
     ]
     try:
         proc = subprocess.run(
@@ -115,7 +129,10 @@ def _build(name: str) -> str | None:
     except (OSError, subprocess.TimeoutExpired) as e:
         return f"{cmd[0]}: {e}"
     if proc.returncode != 0:
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return proc.stderr.strip() or f"{cmd[0]} exited {proc.returncode}"
+    os.replace(tmp, out)
     return None
 
 
@@ -129,11 +146,12 @@ def _lib_for(name: str) -> ctypes.CDLL | None:
             return _libs[name]
         if name in _errors:
             return None
-        err = _build(name)
+        out = _lib_path(name)
+        err = _build(name, out)
         if err is not None:
             _errors[name] = err
             return None
-        lib = ctypes.CDLL(os.path.join(_DIR, "_build", f"libotd_{name}.so"))
+        lib = ctypes.CDLL(out)
         _CONFIGURE[name](lib)
         _libs[name] = lib
         return lib
@@ -919,3 +937,10 @@ def decode_orders(payloads: Sequence[bytes]) -> ColumnarOrders:
     if rc < 0:
         raise ValueError(f"malformed OrderResult payload (code {rc})")
     return ColumnarOrders(value[:n], key[:n], crc[:n])
+
+
+if __name__ == "__main__":
+    for _name in _CONFIGURE:
+        if _lib_for(_name) is None:
+            raise SystemExit(f"native {_name}: {_errors[_name]}")
+        print(f"native {_name}: {_lib_path(_name)}")

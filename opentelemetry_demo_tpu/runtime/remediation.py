@@ -1,7 +1,7 @@
 """Closed-loop auto-mitigation: the verified remediation controller.
 
-The detector flags the 13 shop failure scenarios in 0.25–1.75 s
-(BENCH_r05) — and then a human reads Grafana. This module closes the
+The detector flags the 13 shop failure scenarios in 0.25–1.75 s of
+virtual time (runtime/qualbench.py) — and then a human reads Grafana. This module closes the
 loop through PAPER.md's two control seams: every shop service
 evaluates its fault flags live from the flagd store (``utils/flags``),
 and the pipeline's span stream is sampled by policy. The controller

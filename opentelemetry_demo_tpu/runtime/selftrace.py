@@ -113,7 +113,7 @@ SPAN_FOR_PHASE = {
 
 # Histogram buckets (seconds) for the phase/put-wait/harvest-lag
 # histograms: phases are µs-to-ms host work, harvest lag stretches to
-# the tunneled-RTT regime — one ladder covers both ends.
+# a second under a stalled readback — one ladder covers both ends.
 PHASE_BUCKETS = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0,

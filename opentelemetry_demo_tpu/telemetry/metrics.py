@@ -368,7 +368,12 @@ ANOMALY_FLEET_ADOPTION_TTA = "anomaly_fleet_adoption_seconds"
 ANOMALY_EXPLANATIONS_BUILT = "anomaly_explanations_built_total"
 ANOMALY_EXPLANATIONS_EXPORTED = "anomaly_explanations_exported_total"
 ANOMALY_EXPLAIN_LATENCY = "anomaly_explain_latency_seconds"  # histogram
-ANOMALY_BUILD_INFO = "anomaly_build_info"  # {version=, frame_version=, jax=}
+# {version=, frame_version=, jax=, platform=, device_kind=}
+ANOMALY_BUILD_INFO = "anomaly_build_info"
+# Adaptive-width ladder precompile at boot, one count per width:
+# outcome="error" means that width's step did not compile (the daemon
+# stays up and would pay the failure again at escalation time).
+ANOMALY_WIDTH_WARMUPS = "anomaly_width_warmups_total"  # {width=, outcome=}
 
 # Key lifecycle plane (runtime.keyspace): the budget watchdog's RSS
 # sample (first-class — the soak bench's VmRSS read, promoted to a

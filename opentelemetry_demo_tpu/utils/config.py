@@ -334,7 +334,7 @@ DAEMON_KNOBS: dict[str, tuple[str, object, str]] = {
     "ANOMALY_HARVEST_INTERVAL": (
         "float", 0.0,
         "report readback cadence seconds (0 = harvest every batch; "
-        "set on tunneled/remote devices where readback RTT dominates)",
+        "set where readback RTT dominates the batch interval)",
     ),
     "ANOMALY_HARVEST_ASYNC": (
         "int", 0,

@@ -490,16 +490,12 @@ def main() -> int:
             marker_name in pyproject,
             f"pyproject registers the {marker_name.rstrip(':')} marker",
         )
-    for env_test in (
-        "test_graft_entry.py", "test_multihost.py",
-        "test_parallel.py", "test_tracetest.py",
-    ):
-        ttext = open(os.path.join(ROOT, "tests", env_test)).read()
-        check(
-            "requires_env" in ttext,
-            f"tests/{env_test} carries the requires_env marker "
-            "(its failures are env gaps, not regressions)",
-        )
+    ttext = open(os.path.join(ROOT, "tests", "test_tracetest.py")).read()
+    check(
+        "requires_env" in ttext,
+        "tests/test_tracetest.py carries the requires_env marker "
+        "(its failures are env gaps, not regressions)",
+    )
     history_tests = os.path.join(ROOT, "tests", "test_history.py")
     check(os.path.exists(history_tests), "tests/test_history.py exists")
     if os.path.exists(history_tests):
@@ -682,7 +678,7 @@ def main() -> int:
     #     called ONLY from runtime/native.py (monopoly pin, same
     #     pattern as frame.py's byte-primitive fence — a second caller
     #     would fork the ctypes contract and the GIL-release story),
-    #     native and the Python fallback share ONE verdict taxonomy
+    #     native and the Python fallback share ONE verdict vocabulary
     #     (malformed → ValueError/-1 → the receivers' 400; no new bare
     #     error path), and the decodebench/fuzz surfaces exist.
     native_py = os.path.join(
@@ -728,7 +724,7 @@ def main() -> int:
     for marker in ("scan_request", "extract_span", "otd_scan_otlp",
                    "otd_extract_otlp", "payload_rows"):
         check(marker in cctext, f"native/ingest.cc declares {marker}")
-    # One verdict taxonomy: the pool maps BOTH engines' per-payload
+    # One verdict vocabulary: the pool maps BOTH engines' per-payload
     # verdicts into the same errors dict the receivers answer 400
     # from; native.py raises ValueError for whole-batch failures
     # exactly like otlp.decode_export_request's WireError(ValueError).
@@ -736,7 +732,7 @@ def main() -> int:
     check(
         'ValueError("malformed OTLP payload")' in ptext,
         "ingest pool maps native per-payload verdicts to the "
-        "fallback's ValueError taxonomy",
+        "fallback's ValueError vocabulary",
     )
     check(
         "decodebench:" in open(os.path.join(ROOT, "Makefile")).read(),

@@ -1,4 +1,4 @@
-"""exception-status: no silent swallows; one status taxonomy.
+"""exception-status: no silent swallows; one status vocabulary.
 
 The runtime's error-handling contract, two halves:
 
@@ -12,7 +12,7 @@ The runtime's error-handling contract, two halves:
    catch is where real faults go to disappear; ~50 existing sites all
    carry their reasons, and this pass keeps it that way.
 
-2. **Status taxonomy.** The HTTP/gRPC surfaces answer ONLY from the
+2. **Status vocabulary.** The HTTP/gRPC surfaces answer ONLY from the
    registered status sets (deploy/README's fault matrix is written
    against them): HTTP {200, 204, 400, 404, 405, 411, 413, 415, 429,
    500, 503} and gRPC {OK, INVALID_ARGUMENT, NOT_FOUND,
@@ -47,8 +47,8 @@ DESCRIPTION = (
     "HTTP/gRPC handlers answer only from the registered status sets"
 )
 
-HTTP_TAXONOMY = {200, 204, 400, 404, 405, 411, 413, 415, 429, 500, 503}
-GRPC_TAXONOMY = {
+HTTP_STATUSES = {200, 204, 400, 404, 405, 411, 413, 415, 429, 500, 503}
+GRPC_STATUSES = {
     "OK", "INVALID_ARGUMENT", "NOT_FOUND", "RESOURCE_EXHAUSTED",
     "UNAVAILABLE", "INTERNAL", "UNIMPLEMENTED", "DEADLINE_EXCEEDED",
 }
@@ -133,17 +133,17 @@ def run(repo: Repo) -> list[Violation]:
                         "comment on the clause (`# noqa: BLE001 — why`)",
                     ))
                 continue
-            # -- status taxonomy ---------------------------------------
+            # -- status vocabulary ---------------------------------------
             if rel in http_modules and isinstance(node, ast.Call) and \
                     isinstance(node.func, ast.Attribute) and \
                     node.func.attr in ("send_response", "send_error") and \
                     node.args:
                 for value, line in _status_ints(node.args[0]):
-                    if value not in HTTP_TAXONOMY:
+                    if value not in HTTP_STATUSES:
                         out.append(Violation(
                             PASS_ID, rel, line or node.lineno,
                             f"HTTP status {value} is outside the "
-                            f"registered taxonomy {sorted(HTTP_TAXONOMY)} "
+                            f"registered vocabulary {sorted(HTTP_STATUSES)} "
                             "— the fault matrix and client retry "
                             "policies are written against that set",
                         ))
@@ -152,21 +152,21 @@ def run(repo: Repo) -> list[Violation]:
                     if isinstance(t, ast.Name) and t.id == "status":
                         for value, line in _status_ints(node.value):
                             if 100 <= value <= 599 and \
-                                    value not in HTTP_TAXONOMY:
+                                    value not in HTTP_STATUSES:
                                 out.append(Violation(
                                     PASS_ID, rel, line or node.lineno,
                                     f"HTTP status {value} assigned but "
-                                    "outside the registered taxonomy "
-                                    f"{sorted(HTTP_TAXONOMY)}",
+                                    "outside the registered vocabulary "
+                                    f"{sorted(HTTP_STATUSES)}",
                                 ))
             elif in_runtime and isinstance(node, ast.Attribute) and \
                     isinstance(node.value, ast.Attribute) and \
                     node.value.attr == "StatusCode" and \
                     node.attr.isupper():
-                if node.attr not in GRPC_TAXONOMY:
+                if node.attr not in GRPC_STATUSES:
                     out.append(Violation(
                         PASS_ID, rel, node.lineno,
                         f"gRPC StatusCode.{node.attr} is outside the "
-                        f"registered taxonomy {sorted(GRPC_TAXONOMY)}",
+                        f"registered vocabulary {sorted(GRPC_STATUSES)}",
                     ))
     return out

@@ -7,7 +7,9 @@ gating, checkpoint on shutdown and resume on reboot.
 
 import http.client
 import json
+import logging
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -15,6 +17,7 @@ import pytest
 from opentelemetry_demo_tpu.models import DetectorConfig
 from opentelemetry_demo_tpu.runtime import wire
 from opentelemetry_demo_tpu.runtime.daemon import DetectorDaemon
+from opentelemetry_demo_tpu.runtime.pipeline import DetectorPipeline
 from opentelemetry_demo_tpu.telemetry import metrics as tele_metrics
 
 
@@ -128,6 +131,40 @@ def test_daemon_end_to_end(env):
     finally:
         daemon2.exporter.stop()
         daemon2.receiver.stop()
+
+
+def test_ladder_width_failure_is_logged_and_counted(env, monkeypatch, caplog):
+    """A ladder width that does not compile is logged with its error and
+    counted (outcome="error") while the other widths still warm and the
+    daemon stays up; build_info names the device that serves."""
+    refused = 512
+
+    def warm_width(self, width):
+        if width == refused:
+            raise RuntimeError("refused by the compiler")
+
+    monkeypatch.setattr(DetectorPipeline, "warm_width", warm_width)
+    config = DetectorConfig(num_services=8, hll_p=8, cms_width=512)
+    with caplog.at_level(logging.ERROR):
+        daemon = DetectorDaemon(config)
+        try:
+            for t in threading.enumerate():
+                if t.name == "width-ladder-warmup":
+                    t.join(timeout=30)
+            text = daemon.registry.render()
+        finally:
+            daemon.exporter.stop()
+            daemon.receiver.stop()
+    widths = [256, 512, 1024, 2048]  # ANOMALY_BATCH=256, growth 8×
+    for w in widths:
+        outcome = "error" if w == refused else "ok"
+        assert (
+            f'{tele_metrics.ANOMALY_WIDTH_WARMUPS}{{outcome="{outcome}",'
+            f'width="{w}"}} 1.0' in text
+        ), text
+    assert f"adaptive width {refused} failed to compile" in caplog.text
+    assert "refused by the compiler" in caplog.text
+    assert 'platform="cpu"' in text and 'device_kind="cpu"' in text
 
 
 def test_daemon_metrics_leg_flags_surge(env):

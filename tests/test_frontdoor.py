@@ -3,10 +3,10 @@ column bit-identity, and intern-table scale.
 
 The tentpole's claims, each pinned:
 
-- **Taxonomy parity** — the native acceptor and the Python receiver
+- **Vocabulary parity** — the native acceptor and the Python receiver
   answer the SAME status for every request in a shared seed corpus
   (valid, malformed, bad/oversized Content-Length, empty, odd paths,
-  metrics, logs). One taxonomy, two doors.
+  metrics, logs). One vocabulary, two doors.
 - **Column bit-identity** — the same payloads through either door land
   in the pipeline as bit-identical columns (the front door is a
   transport, never a second decoder).
@@ -215,10 +215,10 @@ class TestParity:
             pool.close()
 
         assert fd_status == py_status, (
-            f"verdict taxonomy drift: native={fd_status} "
+            f"verdict vocabulary drift: native={fd_status} "
             f"python={py_status}"
         )
-        # And the taxonomy is the one the contract names, not merely
+        # And the vocabulary is the one the contract names, not merely
         # self-consistent.
         assert py_status["valid_traces"] == 200
         assert py_status["malformed_traces"] == 400
@@ -444,7 +444,7 @@ class TestFraming:
     def test_frontdoor_faultwire_chaos(self):
         """The chaos proxy between client and acceptor: mid-stream
         truncation kills requests, seeded corruption mangles framing —
-        the acceptor answers its taxonomy (or sheds the conn) and
+        the acceptor answers its vocabulary (or sheds the conn) and
         keeps serving clean traffic throughout."""
         from opentelemetry_demo_tpu.runtime.faultwire import FaultWire
 
@@ -503,7 +503,7 @@ class _StubTicket:
 
 class _StubPool:
     """Duck-typed IngestPool: scripted verdicts for the control-plane
-    tests (the real pool's taxonomy is covered by TestParity)."""
+    tests (the real pool's vocabulary is covered by TestParity)."""
 
     def __init__(self):
         self.mode = "ok"

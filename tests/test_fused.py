@@ -364,6 +364,58 @@ class TestFusedHeadUpdate:
         for a, b_ in zip(ref[4], got[4]):  # z triples
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
 
+    @pytest.mark.parametrize("width", [4096, 8192])
+    def test_folded_heads_at_ladder_widths(self, rng, width):
+        """The adaptive ladder's wider dispatch widths at the DEFAULT
+        tiling (4096: one tile; 8192: two tiles, wide cell chunks) run
+        the same head fold: banks bit-equal to the xla impl, heads and
+        z-scores bit-exact vs the two-step form."""
+        import jax
+
+        s, p, d, w = 32, 8, 4, 1024
+        kw = dict(num_services=s, hll_p=p, cms_width=w)
+        batch = _batch(rng, width, s, d, w, svc_lo=-3, svc_hi=s + 3)
+        hll_cur = jnp.asarray(
+            rng.integers(0, 20, size=(3, s, 1 << p)), jnp.int32
+        )
+        cms_cur = jnp.asarray(
+            rng.integers(0, 1000, size=(3, d, w)), jnp.int32
+        )
+        heads = self._heads(rng, s)
+        dt = jnp.float32(0.05)
+        sp = jnp.asarray(True)
+
+        @jax.jit
+        def two_step(heads):
+            h, c, stats = fused.sketch_batch_update(
+                hll_cur, cms_cur, *batch.values(), impl="interpret", **kw
+            )
+            return h, c, stats, *fused.head_update(
+                stats, heads, dt, sp, **self.HEAD_KW
+            )
+
+        def folded(heads, impl):
+            return jax.jit(
+                lambda heads: fused.sketch_batch_update(
+                    hll_cur, cms_cur, *batch.values(), impl=impl,
+                    heads=heads, dt=dt, step_pos=sp, **self.HEAD_KW, **kw
+                )
+            )(heads)
+
+        ref = two_step(heads)
+        got = folded(heads, "interpret")
+        xla = folded(heads, "xla")
+        for i in (0, 1):  # integer banks: every impl, bit for bit
+            np.testing.assert_array_equal(np.asarray(ref[i]), np.asarray(got[i]))
+            np.testing.assert_array_equal(np.asarray(xla[i]), np.asarray(got[i]))
+        np.testing.assert_array_equal(np.asarray(ref[2]), np.asarray(got[2]))
+        for name, a, b_ in zip(ref[3]._fields, ref[3], got[3]):
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b_), err_msg=name
+            )
+        for a, b_ in zip(ref[4], got[4]):  # z triples
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
     def test_no_stats_roundtrip_in_folded_jaxpr(self, rng):
         """Structural pin for 'no delta round-trips to HBM on the
         NO_COMM path': the folded pallas program contains exactly ONE

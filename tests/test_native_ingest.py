@@ -395,6 +395,28 @@ class TestOrdersParity:
         assert got.attr_crc[0] == zlib.crc32(b"P1")
 
 
+class TestBuildKey:
+    def test_library_name_keys_source_compiler_and_flags(
+        self, tmp_path, monkeypatch
+    ):
+        """A library is only ever loaded from the name its source bytes,
+        compiler and flags hash to: a copied-in or stale ``_build/``
+        file built from anything else is never picked up."""
+        monkeypatch.setattr(native, "_DIR", str(tmp_path))
+        monkeypatch.delenv("CXX", raising=False)
+        src = tmp_path / "x.cc"
+        src.write_text("int f() { return 1; }\n")
+        first = native._lib_path("x")
+        assert native._lib_path("x") == first
+        src.write_text("int f() { return 2; }\n")
+        edited = native._lib_path("x")
+        monkeypatch.setenv("CXX", "clang++")
+        other_cxx = native._lib_path("x")
+        assert len({first, edited, other_cxx}) == 3
+        for path in (first, edited, other_cxx):
+            assert path.startswith(str(tmp_path / "_build" / "libotd_x-"))
+
+
 class TestCrc32:
     def test_matches_zlib(self):
         for s in (b"", b"P-7", b"abcdefgh" * 100, bytes(range(256))):
@@ -488,7 +510,7 @@ class TestDecodeFuzz:
                     float(r.duration_us)
 
     def test_native_and_python_verdicts_agree_on_every_seed(self):
-        """ONE verdict taxonomy across engines: for every mutated
+        """ONE verdict vocabulary across engines: for every mutated
         payload the native scanner's per-payload verdict (-1 vs rows)
         and the Python fallback's (ValueError vs parse) must AGREE —
         a deployment can swap decode engines without a single request

@@ -9,19 +9,6 @@ import numpy as np
 import pytest
 
 import jax
-
-# Env-dependent suite (requires_env marker, pinned in sanitycheck):
-# the sharding layer imports top-level jax.shard_map, which this CI's
-# jax pin predates — the import below would otherwise fail COLLECTION,
-# so the module-level skip must run before it.
-pytestmark = pytest.mark.requires_env("jax.shard_map")
-if not hasattr(jax, "shard_map"):
-    pytest.skip(
-        "requires_env[jax.shard_map]: this jax has no top-level "
-        "shard_map (the parallel package cannot import)",
-        allow_module_level=True,
-    )
-
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P

@@ -326,6 +326,34 @@ class TestConfig:
             env_int("BAD")
 
 
+    @pytest.mark.parametrize("from_env", [True, False])
+    def test_compile_cache_dir(self, tmp_path, from_env):
+        """JAX_COMPILATION_CACHE_DIR, when set, is the cache directory
+        and nothing overrides it; unset, the cache goes to the fixed
+        in-checkout path. In a child: this process keeps its cache off."""
+        import subprocess
+        import sys
+
+        from opentelemetry_demo_tpu.utils.compile_cache import REPO_CACHE_DIR
+
+        env = dict(os.environ)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if from_env:
+            env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import jax\n"
+             "from opentelemetry_demo_tpu.utils.compile_cache import "
+             "enable_compile_cache\n"
+             "print(enable_compile_cache(), "
+             "jax.config.jax_compilation_cache_dir)"],
+            env=env, capture_output=True, text=True, check=True, timeout=60,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ).stdout.split()
+        want = str(tmp_path) if from_env else REPO_CACHE_DIR
+        assert out == [want, want]
+
+
 class TestPipeline:
     def _records(self, rng, n, svc="checkout", lat=300.0):
         return [

@@ -586,7 +586,7 @@ class TestPassDetails:
         assert "BAR_CHECKPOINT'" in violations[0].message
         assert "compose" in violations[0].message
 
-    def test_status_taxonomy_literal_and_assignment(self, tmp_path):
+    def test_status_vocabulary_literal_and_assignment(self, tmp_path):
         files = {
             **BASE,
             "pkg/runtime/otlp.py": """
