@@ -178,6 +178,10 @@ def _mxu_hist_usable(n_bins: int, n_keys: int) -> bool:
     )
 
 
+# The MXU histogram kernel's stable name in device traces.
+KERNEL_HIST = "cms_hist_mxu"
+
+
 def _hist_mxu_kernel(keys_ref, out_ref):
     """One grid step: [1, TB] keys → TRANSPOSED int8 one-hots → MXU
     int8 contraction accumulated into the [HI, 256] int32 count block.
@@ -270,6 +274,7 @@ def _hist_mxu(flat: jnp.ndarray, n_bins: int) -> jnp.ndarray:
     # (the layout the transposed construction needs).
     counts2d = pl.pallas_call(
         _hist_mxu_kernel,
+        name=KERNEL_HIST,
         grid=(n // _HIST_TILE,),
         # int8 one-hots: [HI, TB]+[256, TB] ≈ 3 MiB at TB=8k —
         # comfortably inside the default scoped-VMEM budget (the r4

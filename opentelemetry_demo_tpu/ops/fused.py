@@ -55,6 +55,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import cms, ewma, hll
 
+# Stable names in device traces and HLO metadata, so a trace reduction
+# finds the step's parts after a refactor: the two Pallas kernels, and
+# the named scopes of the XLA path's HLL, CMS and head phases.
+KERNEL_DELTA = "sketch_delta"
+KERNEL_UPDATE = "sketch_update"
+SCOPE_HLL = "hll"
+SCOPE_CMS = "cms"
+SCOPE_HEAD = "head"
+
 
 class SketchDelta(NamedTuple):
     """One batch's mergeable effect on the sketch bank."""
@@ -543,6 +552,7 @@ def _delta_pallas(
 
     hll_d, cms_d, stats = pl.pallas_call(
         functools.partial(_delta_kernel, wide=wide),
+        name=KERNEL_DELTA,
         grid=(nb,),
         # The compiler's default scoped-VMEM budget (16 MiB) sits ~36 KiB
         # under what the grid pipeline requests at very large B; v5e has
@@ -612,29 +622,32 @@ def sketch_batch_delta(
     d = cidx.shape[0]
 
     if impl == "xla":
-        hll_d = hll.hll_update(
-            jnp.zeros((num_services, r), jnp.int32),
-            jnp.where(in_slice, svc, num_services),
-            bucket,
-            rank,
-            valid,
-        )
+        with jax.named_scope(SCOPE_HLL):
+            hll_d = hll.hll_update(
+                jnp.zeros((num_services, r), jnp.int32),
+                jnp.where(in_slice, svc, num_services),
+                bucket,
+                rank,
+                valid,
+            )
         # Unit weights → the scatter-free histogram. cms_update_hist
         # auto-selects its engine: at production geometries on TPU
         # (tile-divisible key counts) that is the MXU one-hot
         # outer-product Pallas kernel — so the "xla" impl embeds a
         # Pallas hist — with sort+searchsorted elsewhere; both are
         # bit-exact and ~2-4× over the duplicate-heavy scatter.
-        cms_d = cms.cms_update_hist(
-            jnp.zeros((d, cms_width), jnp.int32), cidx, valid
-        )
-        cnt, lat_sum, lat_sumsq = ewma.segment_stats(
-            log_lat, svc, num_services, valid=valid
-        )
-        _, err_sum, _ = ewma.segment_stats(
-            is_error, svc, num_services, valid=valid
-        )
-        stats = jnp.stack([cnt, lat_sum, lat_sumsq, err_sum], axis=0)
+        with jax.named_scope(SCOPE_CMS):
+            cms_d = cms.cms_update_hist(
+                jnp.zeros((d, cms_width), jnp.int32), cidx, valid
+            )
+        with jax.named_scope(SCOPE_HEAD):
+            cnt, lat_sum, lat_sumsq = ewma.segment_stats(
+                log_lat, svc, num_services, valid=valid
+            )
+            _, err_sum, _ = ewma.segment_stats(
+                is_error, svc, num_services, valid=valid
+            )
+            stats = jnp.stack([cnt, lat_sum, lat_sumsq, err_sum], axis=0)
         return SketchDelta(hll=hll_d, cms=cms_d, stats=stats)
 
     valid_f = valid.astype(jnp.float32)
@@ -758,6 +771,7 @@ def _update_pallas(
             _update_kernel, wide=wide, n_windows=n_windows,
             fold_heads=fold, head_statics=head_statics,
         ),
+        name=KERNEL_UPDATE,
         grid=(nb,),
         compiler_params=None if interpret else pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
@@ -884,16 +898,17 @@ def sketch_batch_update(
             num_services=num_services, hll_p=hll_p, cms_width=cms_width,
             impl="xla",
         )
-        merged = (
-            jnp.maximum(hll_cur, delta.hll[None]),
-            cms_cur + delta.cms[None],
-            delta.stats,
-        )
+        with jax.named_scope(SCOPE_HLL):
+            hll_new = jnp.maximum(hll_cur, delta.hll[None])
+        with jax.named_scope(SCOPE_CMS):
+            cms_new = cms_cur + delta.cms[None]
+        merged = (hll_new, cms_new, delta.stats)
         if heads is None:
             return merged
-        new_heads, zs = head_update(
-            delta.stats, heads, dt, step_pos, **head_statics
-        )
+        with jax.named_scope(SCOPE_HEAD):
+            new_heads, zs = head_update(
+                delta.stats, heads, dt, step_pos, **head_statics
+            )
         return merged + (new_heads, zs)
 
     valid_f = valid.astype(jnp.float32)

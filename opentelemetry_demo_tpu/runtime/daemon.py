@@ -249,6 +249,14 @@ def _device_identity() -> tuple[str, str]:
     return dev.platform, dev.device_kind
 
 
+def _device_memory_stats() -> dict:
+    """The device's memory counters (``bytes_in_use``,
+    ``peak_bytes_in_use``), or {} where the backend keeps none."""
+    import jax
+
+    return jax.devices()[0].memory_stats() or {}
+
+
 def _jax_version() -> str:
     try:
         import jax
@@ -369,6 +377,11 @@ class DetectorDaemon:
                 submit=submit,
                 sample=float(st["ANOMALY_SELFTRACE_SAMPLE"]),
             )
+        # Backend compiles in this process since boot, exported as
+        # anomaly_compiles_total on the 1 s cadence: a compile while
+        # serving is a shape that escaped the warmed width ladder.
+        self.compiles = selftrace.CompileCounter()
+        self.compiles.start()
         # Provenance log-record export reuses the selftrace collector
         # endpoint: evidence bundles ride the same OTLP pipeline as
         # every other self-observation signal (no second endpoint knob).
@@ -505,6 +518,8 @@ class DetectorDaemon:
             platform=self.device_platform,
             device_kind=self.device_kind,
         )
+        self.registry.counter_add(tele_metrics.ANOMALY_COMPILES, 0.0)
+        self.registry.counter_add(tele_metrics.ANOMALY_REPORTS_SKIPPED, 0.0)
         self.registry.describe(
             tele_metrics.ANOMALY_BUILD_INFO,
             "Constant 1 labelled with package/frame/jax versions and "
@@ -1678,6 +1693,7 @@ class DetectorDaemon:
             # Backpressure: the pipeline's single admission question —
             # late-bound through self so a restarted receiver follows.
             retry_after=lambda: self.pipeline.admission_retry_after(),
+            phase_observe=self._observe_phase,
         )
 
     def _make_grpc_receiver(self, port: int):
@@ -1697,6 +1713,7 @@ class DetectorDaemon:
             max_body_bytes=self.max_body_bytes,
             component_status=self._supervisor.health_status,
             retry_after=lambda: self.pipeline.admission_retry_after(),
+            phase_observe=self._observe_phase,
         )
 
     def _restart_http_receiver(self) -> None:
@@ -1977,6 +1994,10 @@ class DetectorDaemon:
                 tele_metrics.ANOMALY_FLIGHT_DUMPS,
                 f"flight_dump_{reason}", count, reason=reason,
             )
+        self._selftrace_delta(
+            tele_metrics.ANOMALY_REPORTS_SKIPPED, "reports_skipped",
+            self.pipeline.stats.reports_skipped,
+        )
         if self.selftrace is not None:
             stats = self.selftrace.stats()
             self._selftrace_delta(
@@ -2171,6 +2192,15 @@ class DetectorDaemon:
             float(self.pipeline.stats.spans - getattr(self, "_spans_seen", 0)),
         )
         self._spans_seen = self.pipeline.stats.spans
+        # The exact verdict counter: rows whose report has been
+        # harvested (the processed counter above runs ahead of it by
+        # the batches still in flight).
+        harvested = self.pipeline.stats.spans_harvested
+        self.registry.counter_add(
+            tele_metrics.ANOMALY_SPANS_HARVESTED,
+            float(harvested - getattr(self, "_harvested_seen", 0)),
+        )
+        self._harvested_seen = harvested
 
     def _on_metrics_report(self, t_batch, report) -> None:
         names = self.metrics_feed.service_names
@@ -2604,7 +2634,14 @@ class DetectorDaemon:
         self._register_replication_component()
 
     def step(self, t_now: float | None = None) -> None:
-        """One pump + housekeeping tick (public for tests/sims)."""
+        """One pump + housekeeping tick (public for tests/sims), timed
+        as the loop-step span and phase."""
+        with selftrace.span(
+            selftrace.SPAN_STEP, observe=self._observe_phase
+        ):
+            self._step(t_now)
+
+    def _step(self, t_now: float | None) -> None:
         # Fleet gauges for EVERY role (a standby's membership view
         # must be scrapeable too — it inherits the ring at promotion).
         self._export_fleet_stats()
@@ -2656,56 +2693,10 @@ class DetectorDaemon:
         now_mono = time.monotonic()
         if now_mono - getattr(self, "_last_self_report", 0.0) >= 1.0:
             self._last_self_report = now_mono
-            # docker_stats analogue: this container's resource stats on
-            # the same exposition the shop's processes use.
-            if not hasattr(self, "_proc_stats"):
-                from ..telemetry.receivers import ProcessStatsReceiver
-
-                self._proc_stats = ProcessStatsReceiver(
-                    "anomaly-detector", registry=self.registry
-                )
-            self._proc_stats.scrape()
-            self.registry.gauge_set(
-                tele_metrics.ANOMALY_PENDING_ROWS,
-                float(self.pipeline._pending_rows),
-            )
-            self.registry.gauge_set(
-                tele_metrics.ANOMALY_BATCHES_DISPATCHED,
-                float(self.pipeline.stats.batches),
-            )
-            self.registry.gauge_set(
-                tele_metrics.ANOMALY_SPANS_INGESTED,
-                float(self.pipeline.stats.spans),
-            )
-            self.registry.gauge_set(
-                tele_metrics.ANOMALY_LOG_DOCS_STORED,
-                float(self.log_store.count()),
-            )
-            # History-tier gauges on the same 1 s cadence (they walk
-            # the segment dir listing — not per-step work).
-            self._export_history_stats()
-            # Keyspace/RSS gauges on the 1 s cadence too (the RSS
-            # sample is a /proc open+scan; the ladder moves on hold_s
-            # timescales, never sub-second).
-            self._export_keyspace_stats()
-            # Trend context for any later transition dump: a compact
-            # 1 Hz snapshot of where batch time goes right now.
-            spine_st = self.pipeline.spine_stats()
-            self.flight.record(
-                "phase_snapshot",
-                pool_phase_s=(
-                    dict(self.ingest_pool.stats()["phase_s"])
-                    if self.ingest_pool is not None else None
-                ),
-                spine_overlap=(
-                    spine_st["overlap_ratio"] if spine_st else None
-                ),
-                pending_rows=self.pipeline.pending_rows(),
-                lag_p99_ms=self.pipeline.stats.lag_p99_ms(),
-            )
-            # One autoscale observation window per self-report (the
-            # same 1 s cadence ACT_BATCHES counts in).
-            self._autoscale_observe(now_mono)
+            with selftrace.span(
+                selftrace.SPAN_SELF_REPORT, observe=self._observe_phase
+            ):
+                self._self_report(now_mono)
         # Overload gauges/counters every step (not the 1 s cadence):
         # saturation flips sub-second and the chaos tests scrape between
         # steps — a few dict writes, nothing device-side.
@@ -2793,6 +2784,74 @@ class DetectorDaemon:
             # Guarded: a full disk is a degraded snapshot cadence, not
             # a dead detector.
             self._supervisor.run_step("checkpoint", self._checkpoint)
+
+    def _self_report(self, now_mono: float) -> None:
+        """The 1 s self-telemetry scrape: process and device stats,
+        backlog gauges, history and keyspace gauges, a flight-recorder
+        phase snapshot and one autoscale observation window."""
+        # docker_stats analogue: this container's resource stats on
+        # the same exposition the shop's processes use.
+        if not hasattr(self, "_proc_stats"):
+            from ..telemetry.receivers import ProcessStatsReceiver
+
+            self._proc_stats = ProcessStatsReceiver(
+                "anomaly-detector", registry=self.registry
+            )
+        self._proc_stats.scrape()
+        self.registry.gauge_set(
+            tele_metrics.ANOMALY_PENDING_ROWS,
+            float(self.pipeline._pending_rows),
+        )
+        self.registry.gauge_set(
+            tele_metrics.ANOMALY_BATCHES_DISPATCHED,
+            float(self.pipeline.stats.batches),
+        )
+        self.registry.gauge_set(
+            tele_metrics.ANOMALY_SPANS_INGESTED,
+            float(self.pipeline.stats.spans),
+        )
+        self.registry.gauge_set(
+            tele_metrics.ANOMALY_LOG_DOCS_STORED,
+            float(self.log_store.count()),
+        )
+        # History-tier gauges on the same 1 s cadence (they walk
+        # the segment dir listing — not per-step work).
+        self._export_history_stats()
+        # Keyspace/RSS gauges on the 1 s cadence too (the RSS
+        # sample is a /proc open+scan; the ladder moves on hold_s
+        # timescales, never sub-second).
+        self._export_keyspace_stats()
+        # Trend context for any later transition dump: a compact
+        # 1 Hz snapshot of where batch time goes right now.
+        spine_st = self.pipeline.spine_stats()
+        self.flight.record(
+            "phase_snapshot",
+            pool_phase_s=(
+                dict(self.ingest_pool.stats()["phase_s"])
+                if self.ingest_pool is not None else None
+            ),
+            spine_overlap=(
+                spine_st["overlap_ratio"] if spine_st else None
+            ),
+            pending_rows=self.pipeline.pending_rows(),
+            lag_p99_ms=self.pipeline.stats.lag_p99_ms(),
+        )
+        # One autoscale observation window per self-report (the
+        # same 1 s cadence ACT_BATCHES counts in).
+        self._autoscale_observe(now_mono)
+        # Device memory, where the backend reports it (the CPU backend
+        # does not), and the compiles since boot.
+        mem = _device_memory_stats()
+        for kind, key in (("in_use", "bytes_in_use"),
+                          ("peak", "peak_bytes_in_use")):
+            if key in mem:
+                self.registry.gauge_set(
+                    tele_metrics.ANOMALY_DEVICE_MEMORY, float(mem[key]),
+                    kind=kind,
+                )
+        self._selftrace_delta(
+            tele_metrics.ANOMALY_COMPILES, "compiles", self.compiles.count
+        )
 
     def _export_keyspace_stats(self) -> None:
         """anomaly_process_rss_bytes (first-class — the soak bench's
@@ -2908,6 +2967,10 @@ class DetectorDaemon:
             tele_metrics.ANOMALY_INGEST_POOL_UTILIZATION,
             min(busy / (wall * st["workers"]), 1.0),
         )
+        if busy > 0:
+            self.registry.counter_add(
+                tele_metrics.ANOMALY_INGEST_POOL_BUSY, busy
+            )
         seen["busy_s"] = st["busy_s"]
         seen["wall_t"] = now
 
@@ -3709,6 +3772,12 @@ class DetectorDaemon:
             self._quarantine_seen = quarantined
 
     def _checkpoint(self) -> None:
+        with selftrace.span(
+            selftrace.SPAN_CHECKPOINT, observe=self._observe_phase
+        ):
+            self._write_checkpoint()
+
+    def _write_checkpoint(self) -> None:
         # Fence first (a process that has OBSERVED a newer epoch must
         # not write even to an empty path), then the epoch-stamped save
         # (which additionally refuses to replace a newer-epoch snapshot
@@ -3753,7 +3822,14 @@ class DetectorDaemon:
         if on_ready is not None:
             on_ready(self)
         try:
-            while not self._stop.wait(self.pump_interval_s):
+            while True:
+                # The pump's sleep, named on the profiler's host plane
+                # so the device's idle time between passes reads as
+                # waiting, not as unexplained.
+                with selftrace.span(selftrace.SPAN_LOOP_WAIT):
+                    stopped = self._stop.wait(self.pump_interval_s)
+                if stopped:
+                    break
                 # Guarded: one bad step (a transient JAX/transport/
                 # filesystem fault) backs off and retries — the serve
                 # loop of an always-on sidecar must not be one
@@ -3839,6 +3915,7 @@ class DetectorDaemon:
             self._explain_poster.flush(timeout_s=1.0)
             self._explain_poster.close()
         self.exporter.stop()
+        self.compiles.close()
 
 
 def main() -> None:

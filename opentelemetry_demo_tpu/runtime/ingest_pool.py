@@ -88,7 +88,7 @@ import time
 from collections import deque
 from typing import Callable, NamedTuple, Sequence
 
-from . import frame, native
+from . import frame, native, selftrace
 from .otlp import MONITORED_ATTR_KEYS, decode_export_request
 from .selftrace import (
     PHASE_DECODE,
@@ -97,6 +97,10 @@ from .selftrace import (
     PHASE_SUBMIT,
     PHASE_TENSORIZE,
     PHASE_VERIFY,
+    SPAN_DECODE,
+    SPAN_SUBMIT,
+    SPAN_TENSORIZE,
+    SPAN_VERIFY,
 )
 from .tensorize import InternArena, SpanColumns, SpanRecord, SpanTensorizer
 
@@ -409,7 +413,6 @@ class IngestPool:
         self._scratch = ScratchPool(keep=self.workers + 1)
         # Stats (guarded by _stats_lock; read by the daemon's scrape).
         self._stats_lock = threading.Lock()
-        self.submitted = 0
         self.flushes = 0
         self.flushed_spans = 0
         self.coalesced_requests = 0
@@ -428,7 +431,6 @@ class IngestPool:
         }
         self._scratch_corrupt_seen = 0
         self.busy_s = 0.0  # summed across workers
-        self._started = time.monotonic()
         # Drain accounting: jobs submitted but not yet fully processed.
         self._inflight = 0
         self._idle = threading.Condition(self._stats_lock)
@@ -477,13 +479,11 @@ class IngestPool:
 
     def _enqueue(self, item) -> None:
         with self._stats_lock:
-            self.submitted += 1
             self._inflight += 1
         try:
             self._q.put(item, timeout=self.SUBMIT_TIMEOUT_S)
         except queue.Full:
             with self._stats_lock:
-                self.submitted -= 1
                 self._inflight -= 1
                 if self._inflight == 0:
                     self._idle.notify_all()
@@ -549,24 +549,26 @@ class IngestPool:
         # (when a tracer rides along) the ingest segment of the next
         # sampled batch trace — one measurement, three consumers.
         seg: dict[str, float] = {}
+
+        def ledger(phase: str, dt: float) -> None:
+            self._phase(phase, dt, seg)
+
         if payload_jobs:
             if native.available():
-                parts += self._decode_native(payload_jobs, errors, seg, arena)
+                parts += self._decode_native(payload_jobs, errors, ledger, arena)
             else:
-                parts += self._decode_python(payload_jobs, errors, seg)
+                parts += self._decode_python(payload_jobs, errors, ledger)
         if record_jobs:
-            t0 = time.perf_counter()
-            merged: list[SpanRecord] = []
-            for records, _t in record_jobs:
-                merged.extend(records)
-            parts.append(self.tensorizer.columns_from_records(merged))
-            self._phase(PHASE_TENSORIZE, time.perf_counter() - t0, seg)
+            with selftrace.span(SPAN_TENSORIZE, observe=ledger):
+                merged: list[SpanRecord] = []
+                for records, _t in record_jobs:
+                    merged.extend(records)
+                parts.append(self.tensorizer.columns_from_records(merged))
         cols = SpanColumns.concat(parts) if parts else None
         n_rows = cols.rows if cols is not None else 0
         if n_rows:
-            t0 = time.perf_counter()
-            self.submit_columns(cols)
-            self._phase(PHASE_SUBMIT, time.perf_counter() - t0, seg)
+            with selftrace.span(SPAN_SUBMIT, observe=ledger):
+                self.submit_columns(cols)
         if self.selftrace is not None and seg:
             self.selftrace.flush_segment(seg)
         del parts, cols  # drop the worker's view refs: the rows stay
@@ -588,36 +590,34 @@ class IngestPool:
             if ticket is not None:
                 ticket._resolve(None)
 
-    def _decode_native(self, payload_jobs, errors, seg, arena=None) -> list[SpanColumns]:
+    def _decode_native(self, payload_jobs, errors, ledger, arena=None) -> list[SpanColumns]:
         payloads = [p for p, _t in payload_jobs]
         total = sum(len(p) for p in payloads)
-        t0 = time.perf_counter()
-        scratch = self._scratch.acquire(
-            *native.scratch_dims(total, len(payloads))
-        )
+        scratch = None
         parked = False
         native_phases: dict[str, float] = {}
         try:
-            cols, payload_rows = native.decode_otlp_many(
-                payloads, self.attr_keys, scratch,
-                threads=self.native_threads,
-                shard_min_bytes=self.shard_min_bytes,
-                phases=native_phases,
-            )
-            for i, rows in enumerate(payload_rows):
-                if rows < 0:
-                    errors[i] = ValueError("malformed OTLP payload")
-            # Phase sample BEFORE the empty-flush return: an all-
-            # malformed flood burns real decode time and the
-            # attribution must show it. scan/extract are the native
+            # The decode span is observed BEFORE the empty-flush
+            # return: an all-malformed flood burns real decode time and
+            # the attribution must show it. scan/extract are the native
             # call's own two-pass split — sub-phases of the decode
             # envelope, never added into a share denominator
             # (TOP_PHASES).
-            self._phase(PHASE_DECODE, time.perf_counter() - t0, seg)
-            self._phase(PHASE_SCAN, native_phases.get("scan", 0.0), seg)
-            self._phase(
-                PHASE_EXTRACT, native_phases.get("extract", 0.0), seg
-            )
+            with selftrace.span(SPAN_DECODE, observe=ledger):
+                scratch = self._scratch.acquire(
+                    *native.scratch_dims(total, len(payloads))
+                )
+                cols, payload_rows = native.decode_otlp_many(
+                    payloads, self.attr_keys, scratch,
+                    threads=self.native_threads,
+                    shard_min_bytes=self.shard_min_bytes,
+                    phases=native_phases,
+                )
+                for i, rows in enumerate(payload_rows):
+                    if rows < 0:
+                        errors[i] = ValueError("malformed OTLP payload")
+            ledger(PHASE_SCAN, native_phases.get("scan", 0.0))
+            ledger(PHASE_EXTRACT, native_phases.get("extract", 0.0))
             if not cols.duration_us.shape[0]:
                 return []
             # Zero-copy hand-off (the ingest spine): the pipeline
@@ -634,14 +634,12 @@ class IngestPool:
             # evidence (see ScratchPool). The recycled-early race the
             # old copy guarded against cannot happen: a still-
             # referenced scratch is simply never handed out again.
-            t0 = time.perf_counter()
-            crcs = frame.span_column_crcs(cols)
-            self._phase(PHASE_VERIFY, time.perf_counter() - t0, seg)
-            t0 = time.perf_counter()
-            out = self.tensorizer.columns_from_columnar(
-                cols, copy=False, arena=arena
-            )
-            self._phase(PHASE_TENSORIZE, time.perf_counter() - t0, seg)
+            with selftrace.span(SPAN_VERIFY, observe=ledger):
+                crcs = frame.span_column_crcs(cols)
+            with selftrace.span(SPAN_TENSORIZE, observe=ledger):
+                out = self.tensorizer.columns_from_columnar(
+                    cols, copy=False, arena=arena
+                )
             if cols.duration_us.base is scratch.duration:
                 self._scratch.park(scratch, cols, crcs)
                 parked = True
@@ -651,7 +649,7 @@ class IngestPool:
             # and goes straight back to the freelist.
             return [out]
         finally:
-            if not parked:
+            if scratch is not None and not parked:
                 self._scratch.release(scratch)
 
     def _phase(self, name: str, dt: float, seg: dict | None = None) -> None:
@@ -693,17 +691,16 @@ class IngestPool:
             except Exception:  # noqa: BLE001 — forensics must never
                 pass  # compound the fault (same rule as quarantine())
 
-    def _decode_python(self, payload_jobs, errors, seg) -> list[SpanColumns]:
+    def _decode_python(self, payload_jobs, errors, ledger) -> list[SpanColumns]:
         """No-compiler fallback: per-request wire decode, still ONE
         coalesced tensorize pass per flush."""
-        t0 = time.perf_counter()
         merged: list[SpanRecord] = []
-        for i, (payload, _t) in enumerate(payload_jobs):
-            try:
-                merged.extend(decode_export_request(payload))
-            except Exception as e:  # noqa: BLE001 — per-request verdict
-                errors[i] = e
-        self._phase(PHASE_DECODE, time.perf_counter() - t0, seg)
+        with selftrace.span(SPAN_DECODE, observe=ledger):
+            for i, (payload, _t) in enumerate(payload_jobs):
+                try:
+                    merged.extend(decode_export_request(payload))
+                except Exception as e:  # noqa: BLE001 — per-request verdict
+                    errors[i] = e
         if not merged:
             return []
         return [self.tensorizer.columns_from_records(merged)]
@@ -747,10 +744,8 @@ class IngestPool:
     def stats(self) -> dict:
         """Point-in-time counters for the daemon's metrics scrape."""
         with self._stats_lock:
-            wall = max(time.monotonic() - self._started, 1e-9)
             return {
                 "depth": self._q.qsize(),
-                "submitted": self.submitted,
                 "flushes": self.flushes,
                 "flushed_spans": self.flushed_spans,
                 "coalesced_requests": self.coalesced_requests,
@@ -761,9 +756,5 @@ class IngestPool:
                 "phase_s": dict(self.phase_s),
                 "tickets_parked": self._scratch.tickets_parked,
                 "tickets_recycled": self._scratch.tickets_recycled,
-                "scratch_parked": self._scratch.parked(),
                 "workers": self.workers,
-                # Lifetime busy fraction; the daemon exports a windowed
-                # delta-based gauge on top of busy_s/wall.
-                "utilization": min(self.busy_s / (wall * self.workers), 1.0),
             }

@@ -25,7 +25,8 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable
 
-from . import native, wire
+from . import native, selftrace, wire
+from .selftrace import PHASE_DOOR, SPAN_DOOR
 from .tensorize import SpanEvent, SpanRecord
 
 _STATUS_ERROR = 2  # opentelemetry.proto.trace.v1.Status.StatusCode.ERROR
@@ -379,6 +380,7 @@ class OtlpHttpReceiver:
         max_body_bytes: int = 16 << 20,
         retry_after: Callable[[], float | None] | None = None,
         on_payload: Callable | None = None,
+        phase_observe: Callable[[str, float], None] | None = None,
     ):
         receiver = self
 
@@ -387,13 +389,25 @@ class OtlpHttpReceiver:
 
             def do_POST(self):  # noqa: N802 (http.server API)
                 path = self.path.split("?", 1)[0]
+                # The door span: handler entry to the answer written,
+                # the decode-ticket wait included. Its phase counts
+                # accepted trace exports only; refusals and failures
+                # are counted by their own reject reasons.
+                with selftrace.span(SPAN_DOOR) as door:
+                    accepted = self._post(path)
+                if accepted and receiver.phase_observe is not None:
+                    receiver.phase_observe(PHASE_DOOR, door.seconds)
+
+            def _post(self, path: str) -> bool:
+                """Answer one POST; True once a trace export got its
+                200."""
                 try:
                     length = int(self.headers.get("Content-Length", 0))
                 except ValueError:
                     receiver._reject("bad_length")
                     self.send_response(400)
                     self.end_headers()
-                    return
+                    return False
                 if length > receiver.max_body_bytes:
                     # Oversized: refuse WITHOUT reading — draining a
                     # multi-GB body to politely answer 413 is itself a
@@ -404,7 +418,7 @@ class OtlpHttpReceiver:
                     self.send_header("Connection", "close")
                     self.end_headers()
                     self.close_connection = True
-                    return
+                    return False
                 if retry_after is not None and not (
                     path.endswith("/v1/metrics") or path.endswith("/v1/logs")
                 ):
@@ -428,14 +442,14 @@ class OtlpHttpReceiver:
                         except OSError:
                             receiver._reject("disconnect")
                             self.close_connection = True
-                            return
+                            return False
                         receiver._reject("saturated")
                         self.send_response(429)
                         self.send_header(
                             "Retry-After", str(max(int(-(-hint // 1)), 1))
                         )
                         self.end_headers()
-                        return
+                        return False
                 try:
                     body = self.rfile.read(length)
                 except OSError:
@@ -443,14 +457,14 @@ class OtlpHttpReceiver:
                     # nothing to answer, just release the thread.
                     receiver._reject("disconnect")
                     self.close_connection = True
-                    return
+                    return False
                 if len(body) < length:
                     # Truncated frame: the client promised more bytes
                     # than it sent (died mid-upload). 4xx, not a crash.
                     receiver._reject("truncated")
                     self.send_response(400)
                     self.end_headers()
-                    return
+                    return False
                 is_json = "json" in (self.headers.get("Content-Type") or "")
                 is_traces = not (
                     path.endswith("/v1/metrics") or path.endswith("/v1/logs")
@@ -477,7 +491,7 @@ class OtlpHttpReceiver:
                         self.send_response(429)
                         self.send_header("Retry-After", "1")
                         self.end_headers()
-                        return
+                        return False
                     try:
                         ticket.result()
                     except TimeoutError:
@@ -489,14 +503,14 @@ class OtlpHttpReceiver:
                         self.send_response(503)
                         self.send_header("Retry-After", "1")
                         self.end_headers()
-                        return
+                        return False
                     except IngestWorkerError:
                         # Server-side flush failure: our bug, not the
                         # client's bytes — must surface as 5xx, never
                         # masquerade as "malformed".
                         self.send_response(500)
                         self.end_headers()
-                        return
+                        return False
                     except Exception:
                         # The pool's per-request DECODE verdict (any
                         # exception the payload raised while being
@@ -506,7 +520,7 @@ class OtlpHttpReceiver:
                         receiver._reject("malformed")
                         self.send_response(400)
                         self.end_headers()
-                        return
+                        return False
                     try:
                         self.send_response(200)
                         self.send_header(
@@ -517,7 +531,8 @@ class OtlpHttpReceiver:
                     except OSError:
                         receiver._reject("disconnect")
                         self.close_connection = True
-                    return
+                        return False
+                    return True
                 columnar = None
                 metric_records = None
                 log_records = None
@@ -558,7 +573,7 @@ class OtlpHttpReceiver:
                     receiver._reject("malformed")
                     self.send_response(400)
                     self.end_headers()
-                    return
+                    return False
                 if log_records is not None:
                     if receiver.on_log_records is not None:
                         receiver.on_log_records(log_records)
@@ -582,6 +597,8 @@ class OtlpHttpReceiver:
                     # lost — count it, release the thread.
                     receiver._reject("disconnect")
                     self.close_connection = True
+                    return False
+                return is_traces
 
             def log_message(self, *args):  # silence per-request stderr spam
                 pass
@@ -594,6 +611,8 @@ class OtlpHttpReceiver:
         self.on_reject = on_reject
         self.max_body_bytes = max_body_bytes
         self.retry_after = retry_after
+        # The door's phase hook (``anomaly_phase_seconds{phase="door"}``).
+        self.phase_observe = phase_observe
         # reason → count; the daemon mirrors these into
         # anomaly_ingest_rejected_total{transport="http",reason=...}.
         self.rejects: dict[str, int] = {}

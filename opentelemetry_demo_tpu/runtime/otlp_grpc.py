@@ -19,7 +19,8 @@ from __future__ import annotations
 
 from typing import Callable
 
-from . import otlp, otlp_metrics
+from . import otlp, otlp_metrics, selftrace
+from .selftrace import PHASE_DOOR, SPAN_DOOR
 from .tensorize import SpanRecord
 
 TRACE_EXPORT = "/opentelemetry.proto.collector.trace.v1.TraceService/Export"
@@ -75,6 +76,7 @@ class OtlpGrpcReceiver:
         component_status: Callable[[str], int | None] | None = None,
         retry_after: Callable[[], float | None] | None = None,
         on_payload: Callable | None = None,
+        phase_observe: Callable[[str, float], None] | None = None,
     ):
         import grpc
         from concurrent import futures
@@ -86,6 +88,8 @@ class OtlpGrpcReceiver:
         self.on_log_records = on_log_records
         self.on_reject = on_reject
         self.retry_after = retry_after
+        # The door's phase hook (``anomaly_phase_seconds{phase="door"}``).
+        self.phase_observe = phase_observe
         self.rejects: dict[str, int] = {}
         receiver = self
 
@@ -98,6 +102,16 @@ class OtlpGrpcReceiver:
                     pass
 
         def export_traces(request: bytes, context) -> bytes:
+            # The door span, as on the HTTP leg: entry to the answer,
+            # the decode-ticket wait included; an aborted RPC raises
+            # out of it and is not observed as a door pass.
+            with selftrace.span(SPAN_DOOR) as door:
+                out = admit_traces(request, context)
+            if receiver.phase_observe is not None:
+                receiver.phase_observe(PHASE_DOOR, door.seconds)
+            return out
+
+        def admit_traces(request: bytes, context) -> bytes:
             if receiver.retry_after is not None:
                 hint = receiver.retry_after()
                 if hint is not None:

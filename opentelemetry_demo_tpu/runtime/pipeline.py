@@ -48,6 +48,7 @@ import numpy as np
 from ..models.detector import AnomalyDetector, DetectorReport, report_unpack
 from ..ops.hashing import splitmix64_np
 from ..utils.flags import FlagEvaluator
+from . import selftrace
 from .provenance import (
     REASON_CARDINALITY,
     REASON_CUSUM,
@@ -56,17 +57,14 @@ from .provenance import (
     REASON_THROUGHPUT,
 )
 from .selftrace import (
-    PHASE_DISPATCH,
-    PHASE_FLAG,
-    PHASE_HARVEST,
     PHASE_HARVEST_LAG,
-    PHASE_PUT_WAIT,
-    PHASE_STAGE,
+    PHASE_QUEUE_WAIT,
+    PHASE_REPORT_WAIT,
+    PHASE_RING_WAIT,
     SPAN_DISPATCH,
+    SPAN_EXPORT,
     SPAN_FLAG,
     SPAN_HARVEST,
-    SPAN_PUT,
-    SPAN_STAGE,
 )
 from .tensorize import SpanColumns, SpanRecord, SpanTensorizer
 
@@ -107,6 +105,11 @@ class PipelineStats:
     batches: int = 0
     spans: int = 0
     dropped_disabled: int = 0
+    # Dispatched rows whose verdict has been harvested: at each
+    # harvested report, the cumulative ``spans`` as of that report's
+    # batch. A skipped report's rows are credited by the next harvested
+    # report, which covers them (device state folded them in order).
+    spans_harvested: int = 0
     flag_events: int = 0
     # Reports dropped unfetched under a harvest interval (their batches
     # still updated device state; only the host-side readback skipped).
@@ -248,6 +251,7 @@ class DetectorPipeline:
                 depth=spine_ring,
                 overlap=spine_overlap,
                 chunk_rows=spine_chunk_rows,
+                phase_observe=phase_observe,
             )
         self.max_wait_s = max_wait_s
         # Device→host readback cadence. 0 = harvest a report every pump
@@ -478,7 +482,7 @@ class DetectorPipeline:
             if not cols.rows:
                 return
         with self._pending_lock:
-            self._pending.append((cols, time.monotonic()))
+            self._pending.append((cols, selftrace.clock()))
             self._pending_rows += cols.rows
             if self.queue_max_rows and self._pending_rows > self.queue_max_rows:
                 self._shed_locked()
@@ -832,6 +836,7 @@ class DetectorPipeline:
                     budget -= head.rows
             self._pending_rows -= sum(p.rows for p in parts)
             rows_after = self._pending_rows
+            t_take = selftrace.clock()
         # Re-check with the batch removed: a drain that just crossed
         # the low watermark must reopen the gate THIS pump, not next.
         self._admission_update(rows_after)
@@ -869,12 +874,14 @@ class DetectorPipeline:
                 # backpressure, and the pump is the only consumer.
                 while self._spine.pending() >= self._spine.depth:
                     self._pump_spine(force_wait=True)
-                self._spine.stage(cols, width, t_now, t_oldest, trace=trace)
+                self._spine.stage(
+                    cols, width, t_now, t_oldest, t_take, trace=trace
+                )
                 self._pump_spine()
             else:
                 batch = self.tensorizer.pack_columns(cols, width=width)
                 self._dispatch_batch(
-                    batch, t_now, t_oldest, cols, batch.num_valid,
+                    batch, t_now, t_oldest, t_take, cols, batch.num_valid,
                     trace=trace,
                 )
         if self.harvest_async:
@@ -890,7 +897,7 @@ class DetectorPipeline:
             self._maybe_sync_harvest(keep=keep)
 
     def _dispatch_batch(
-        self, batch, t_now, t_oldest, cols, n_valid: int, trace=None
+        self, batch, t_now, t_oldest, t_take, cols, n_valid: int, trace=None
     ) -> None:
         """Dispatch ONE packed batch (host- or device-resident) into
         the donated step — the single place detector state advances
@@ -898,22 +905,27 @@ class DetectorPipeline:
         self._last_dispatch = time.monotonic()
         if self.history_capture is not None and cols is not None:
             self.history_capture(cols, t_now)
-        t0 = time.perf_counter()
-        # Packed dispatch: the report comes back as ONE device vector so
-        # harvest is a single transfer instead of one per report leaf.
-        with self._dispatch_lock:
-            report = self.detector.observe_packed(batch, t_now)  # async dispatch
-        try:
-            # Start the device→host copy now; by harvest time the bytes
-            # are (mostly) on host and device_get degenerates to a wait.
-            report.copy_to_host_async()
-        except AttributeError:  # non-jax.Array stand-ins in tests
-            pass
-        dispatch_dt = time.perf_counter() - t0
+        with selftrace.span(
+            SPAN_DISPATCH, observe=self.phase_observe, trace=trace
+        ) as dispatch:
+            # Packed dispatch: the report comes back as ONE device
+            # vector so harvest is a single transfer instead of one per
+            # report leaf.
+            with self._dispatch_lock:
+                report = self.detector.observe_packed(batch, t_now)  # async dispatch
+            try:
+                # Start the device→host copy now; by harvest time the
+                # bytes are (mostly) on host and device_get degenerates
+                # to a wait.
+                report.copy_to_host_async()
+            except AttributeError:  # non-jax.Array stand-ins in tests
+                pass
         if self.phase_observe is not None:
-            self.phase_observe(PHASE_DISPATCH, dispatch_dt)
+            # The batch's two waits before dispatch, observed after it
+            # so nothing between take and dispatch takes a lock.
+            self.phase_observe(PHASE_QUEUE_WAIT, t_take - t_oldest)
+            self.phase_observe(PHASE_RING_WAIT, dispatch.t0 - t_take)
         if trace is not None:
-            trace.span(SPAN_DISPATCH, dispatch_dt)
             trace.attrs.append(("batch.rows", str(int(n_valid))))
         self.stats.batches += 1
         self.stats.spans += n_valid
@@ -921,12 +933,17 @@ class DetectorPipeline:
             # Lag clock = the oldest row's enqueue time, not dispatch
             # time: under the adaptive accumulate-hold rows can wait up
             # to hold_s before dispatch, and that wait IS detection lag.
+            # The dispatch's end stamp starts the report wait, and the
+            # cumulative dispatched rows credit the harvested counter.
             # The host-side columns ride along so the harvester can
             # capture exemplar trace ids AT FLAG TIME from the exact
             # batch that flagged (bounded: ≤3 batches in flight). The
             # batch's sampled self-trace (or None) rides too — the
             # harvester finishes it after the flag decision.
-            self._inflight.append((t_now, t_oldest, report, cols, trace))
+            self._inflight.append((
+                t_now, t_oldest, report, cols, trace, dispatch.t1,
+                self.stats.spans,
+            ))
             # Bound the in-flight window: stale reports are dropped
             # unfetched (their batches already updated device state) so
             # readback RTT never throttles dispatch.
@@ -956,21 +973,13 @@ class DetectorPipeline:
         staged = self._spine.take(wait=must_wait)
         if staged is None:
             return False
-        if self.phase_observe is not None:
-            self.phase_observe(PHASE_STAGE, staged.stage_dur)
-            self.phase_observe(PHASE_PUT_WAIT, staged.wait_s)
-        if staged.trace is not None:
-            staged.trace.span(SPAN_STAGE, staged.stage_dur)
-            staged.trace.span(
-                SPAN_PUT, staged.wait_s,
-                attrs=(("overlap.hit", str(int(staged.wait_s == 0.0))),),
-            )
         # n_valid from the host row count: the device batch's own
         # valid.sum() would force a device sync on the dispatch path.
         self._dispatch_batch(
             staged.batch,
             staged.t_now,
             staged.t_oldest,
+            staged.t_take,
             staged.cols,
             staged.cols.rows,
             trace=staged.trace,
@@ -1584,28 +1593,33 @@ class DetectorPipeline:
     # -- report processing --------------------------------------------
 
     def _process_report(self, item) -> None:
-        t_batch, t_dispatch, dev_report, cols, trace = item
+        t_batch, t_oldest, dev_report, cols, trace, t_sent, rows = item
         self._note_outcome(skipped=False)
         probe = self._start_rtt_probe() if self.rtt_probe else None
         # Single-array fetch + host-side unpack (see pump()).
-        t_fetch = time.perf_counter()
-        report = report_unpack(jax.device_get(dev_report), self.detector.config)
-        fetch_dt = time.perf_counter() - t_fetch
+        with selftrace.span(
+            SPAN_HARVEST, observe=self.phase_observe, trace=trace
+        ) as fetch:
+            report = report_unpack(
+                jax.device_get(dev_report), self.detector.config
+            )
+        # Oldest row's enqueue → fetched: the batch's detection lag,
+        # tiled exactly by queue_wait + ring_wait + dispatch +
+        # report_wait + harvest (one clock, shared stamps).
+        lag_s = fetch.t1 - t_oldest
+        self.stats.lag_ms.append(lag_s * 1e3)
+        self.stats.spans_harvested = max(self.stats.spans_harvested, rows)
+        if self.phase_observe is not None:
+            self.phase_observe(PHASE_REPORT_WAIT, fetch.t0 - t_sent)
+            # Submit→harvest lag is its own histogram (the detection-lag
+            # SLO's distribution), distinct from the fetch cost above.
+            self.phase_observe(PHASE_HARVEST_LAG, lag_s)
         flags_np = report.flags
         if self._provenance is not None:
             # Ring the head trajectories on EVERY harvested report —
             # already host numpy, so the K-window evidence history
             # costs an append, never a device round trip.
             self._provenance.observe_report(float(t_batch), report)
-        lag_ms = (time.monotonic() - t_dispatch) * 1e3
-        self.stats.lag_ms.append(lag_ms)
-        if self.phase_observe is not None:
-            self.phase_observe(PHASE_HARVEST, fetch_dt)
-            # Submit→harvest lag is its own histogram (the detection-lag
-            # SLO's distribution), distinct from the fetch cost above.
-            self.phase_observe(PHASE_HARVEST_LAG, lag_ms / 1e3)
-        if trace is not None:
-            trace.span(SPAN_HARVEST, fetch_dt)
         if probe is not None:
             probe["thread"].join(timeout=10.0)
             self.stats.rtt_ms.append(probe["res"].get("rtt", float("nan")))
@@ -1631,45 +1645,45 @@ class DetectorPipeline:
             cusum_alarm = (report.cusum > cusum_thr[None, :]).any(axis=1)
             flags_np = (z > threshold) | cusum_alarm
         if flags_np.any():
-            t_flag = time.perf_counter()
-            self.stats.flag_events += 1
-            names = self.tensorizer.service_names
-            flagged = [
-                names[i] if i < len(names) else f"svc-{i}"
-                for i in np.nonzero(flags_np)[0]
-            ]
-            prov_state = (
-                self._provenance_snapshot()
-                if self._provenance is not None
-                else None
-            )
-            links = self._capture_exemplars(
-                t_batch, cols, report, flags_np, threshold,
-                prov_state=prov_state,
-                trace_id=(
-                    trace.trace_id.hex() if trace is not None else None
-                ),
-            )
-            flag_dt = time.perf_counter() - t_flag
-            if self.phase_observe is not None:
-                self.phase_observe(PHASE_FLAG, flag_dt)
-            if trace is not None:
+            with selftrace.span(
+                SPAN_FLAG, observe=self.phase_observe, trace=trace
+            ) as flag:
+                self.stats.flag_events += 1
+                names = self.tensorizer.service_names
+                flagged = [
+                    names[i] if i < len(names) else f"svc-{i}"
+                    for i in np.nonzero(flags_np)[0]
+                ]
+                prov_state = (
+                    self._provenance_snapshot()
+                    if self._provenance is not None
+                    else None
+                )
                 # The flag span carries span LINKS to the exemplar shop
                 # traces captured from THIS batch — a detector batch
                 # trace in Jaeger jumps straight to the evidence.
-                trace.span(
-                    SPAN_FLAG, flag_dt,
-                    attrs=(("flagged.services", ",".join(flagged)),),
-                    links=tuple(links),
-                )
+                flag.links = tuple(self._capture_exemplars(
+                    t_batch, cols, report, flags_np, threshold,
+                    prov_state=prov_state,
+                    trace_id=(
+                        trace.trace_id.hex() if trace is not None else None
+                    ),
+                ))
+                flag.attrs = (("flagged.services", ",".join(flagged)),)
         else:
             flagged = []
-        if trace is not None:
-            try:
-                self._selftrace.finish(trace)
-            except Exception:  # noqa: BLE001 — self-telemetry export
-                # must never fail the report path it observes: the
-                # trace is advisory, the report is the product.
-                pass
-        if self.on_report is not None:
-            self.on_report(t_batch, report, flagged)
+        try:
+            if self.on_report is not None:
+                with selftrace.span(
+                    SPAN_EXPORT, observe=self.phase_observe, trace=trace
+                ):
+                    self.on_report(t_batch, report, flagged)
+        finally:
+            if trace is not None:
+                try:
+                    self._selftrace.finish(trace)
+                except Exception:  # noqa: BLE001 — self-telemetry
+                    # export must never fail the report path it
+                    # observes: the trace is advisory, the report is
+                    # the product.
+                    pass

@@ -35,7 +35,11 @@ collector seam: the otlphttp exporters and the Jaeger surface):
 The same module owns the **phase vocabulary** for the promoted
 ``anomaly_phase_seconds{phase=}`` histograms (telemetry.metrics): one
 table, so the tracer's span names, the histogram's label values and
-the Grafana panels can never drift.
+the Grafana panels can never drift. Every timed block of the served
+path goes through one helper, :class:`span`: a profiler annotation
+under the span's name (the block lands on the device trace's clock),
+a timing on :data:`clock`, the phase observation and the sampled
+trace's span, in one ``with``.
 
 Knob registry: ``utils.config.SELFTRACE_KNOBS`` (enable / sample /
 endpoint / flight ring / flight dir), threaded daemon → compose → k8s
@@ -45,9 +49,13 @@ like every family.
 from __future__ import annotations
 
 import itertools
+import threading
 import time
 from collections import deque
 from typing import Callable
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from . import wire
 
@@ -75,6 +83,17 @@ SPAN_PUT = "detector.device_put"
 SPAN_DISPATCH = "detector.dispatch"
 SPAN_HARVEST = "detector.harvest"
 SPAN_FLAG = "detector.flag"
+# Spans around the daemon's own layers (the served path outside a
+# batch): one OTLP trace request at a door, the serve loop's sleep and
+# pass, the 1 s self-report scrape, the checkpoint write, and one
+# report's export. Each lands on the profiler's host plane, so a device
+# idle gap in a trace is named by what the daemon thread was doing.
+SPAN_DOOR = "detector.door"
+SPAN_LOOP_WAIT = "detector.loop_wait"
+SPAN_STEP = "detector.step"
+SPAN_SELF_REPORT = "detector.self_report"
+SPAN_CHECKPOINT = "detector.checkpoint"
+SPAN_EXPORT = "detector.export"
 
 # -- phase-label table (anomaly_phase_seconds{phase=} vocabulary) ------
 PHASE_DECODE = "decode"
@@ -94,6 +113,22 @@ PHASE_DISPATCH = "dispatch"
 PHASE_HARVEST = "harvest"
 PHASE_HARVEST_LAG = "harvest_lag"
 PHASE_FLAG = "flag"
+PHASE_DOOR = "door"
+PHASE_LOOP_STEP = "loop_step"
+PHASE_SELF_REPORT = "self_report"
+PHASE_CHECKPOINT = "checkpoint"
+PHASE_EXPORT = "export"
+# The three waits of a batch between its layers, computed from the
+# stamps on one clock (``clock``) and observed as phases with no span:
+# in the device trace they are the gaps between spans. With dispatch
+# and harvest they tile ``harvest_lag`` exactly, report by report:
+#   queue_wait   oldest row's enqueue -> the pump takes the batch
+#   ring_wait    take -> dispatch start (spine staging and put, or the
+#                in-tick pack)
+#   report_wait  dispatch end -> the report's fetch starts
+PHASE_QUEUE_WAIT = "queue_wait"
+PHASE_RING_WAIT = "ring_wait"
+PHASE_REPORT_WAIT = "report_wait"
 
 # Phase → span-name projection (the flush segments arrive keyed by
 # phase label; the trace renders them as spans).
@@ -109,7 +144,19 @@ SPAN_FOR_PHASE = {
     PHASE_DISPATCH: SPAN_DISPATCH,
     PHASE_HARVEST: SPAN_HARVEST,
     PHASE_FLAG: SPAN_FLAG,
+    PHASE_DOOR: SPAN_DOOR,
+    PHASE_LOOP_STEP: SPAN_STEP,
+    PHASE_SELF_REPORT: SPAN_SELF_REPORT,
+    PHASE_CHECKPOINT: SPAN_CHECKPOINT,
+    PHASE_EXPORT: SPAN_EXPORT,
 }
+# ... and back: the phase a timed span observes (spans without one,
+# such as SPAN_LOOP_WAIT, only annotate and time).
+PHASES_BY_SPAN = {name: phase for phase, name in SPAN_FOR_PHASE.items()}
+
+# The one clock of the served path's stamps: row enqueue, take,
+# dispatch, fetch, and every span below.
+clock = time.perf_counter
 
 # Histogram buckets (seconds) for the phase/put-wait/harvest-lag
 # histograms: phases are µs-to-ms host work, harvest lag stretches to
@@ -195,6 +242,88 @@ class BatchTrace:
             (name, end - max(duration_s, 0.0), max(duration_s, 0.0),
              tuple(attrs), tuple(links))
         )
+
+
+class span:
+    """Time one block of the served path under a ``SPAN_*`` name.
+
+    On enter it opens a ``jax.profiler.TraceAnnotation`` under the name
+    (so the block lands on the profiler's host plane, on the device
+    trace's clock, in the thread that ran it) and stamps ``t0`` on
+    :data:`clock`; on exit it stamps ``t1`` and then
+
+    - calls ``observe(phase, seconds)`` with the name's phase label
+      (``PHASES_BY_SPAN``) — the ``anomaly_phase_seconds`` hook;
+    - appends the span, with ``attrs`` and ``links``, to ``trace`` (a
+      sampled :class:`BatchTrace` riding the block, or None).
+
+    Both are skipped when None, and neither runs when no phase or trace
+    is given. The block may set ``attrs``/``links`` before it ends (the
+    flag span learns its links inside). Exceptions propagate; the time
+    up to them is still recorded. With no profiler session the
+    annotation costs well under a microsecond.
+    """
+
+    __slots__ = ("name", "observe", "trace", "attrs", "links", "t0", "t1", "_note")
+
+    def __init__(self, name: str, observe: Callable[[str, float], None] | None = None,
+                 trace: BatchTrace | None = None, attrs: tuple = ()):
+        self.name = name
+        self.observe = observe
+        self.trace = trace
+        self.attrs = attrs
+        self.links: tuple = ()
+        self.t0 = self.t1 = 0.0
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> span:
+        self._note = TraceAnnotation(self.name)
+        self._note.__enter__()
+        self.t0 = clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = clock()
+        self._note.__exit__(*exc)
+        if self.observe is not None:
+            phase = PHASES_BY_SPAN.get(self.name)
+            if phase is not None:
+                self.observe(phase, self.t1 - self.t0)
+        if self.trace is not None:
+            self.trace.span(self.name, self.t1 - self.t0, end_perf=self.t1,
+                            attrs=self.attrs, links=self.links)
+
+
+class CompileCounter:
+    """Backend compiles in this process since :meth:`start` — a
+    ``jax.monitoring`` listener on the compile-duration event (the
+    daemon exports it as ``anomaly_compiles_total``). A compile inside
+    a serving window is a shape that escaped the warmed ladder."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.count = 0
+        self._lock = threading.Lock()
+        self._listening = False
+
+    def _on_event(self, event: str, _duration_s: float, **_kw) -> None:
+        if event == self.EVENT:
+            with self._lock:
+                self.count += 1
+
+    def start(self) -> None:
+        if not self._listening:
+            jax.monitoring.register_event_duration_secs_listener(self._on_event)
+            self._listening = True
+
+    def close(self) -> None:
+        if self._listening:
+            jax.monitoring.unregister_event_duration_listener(self._on_event)
+            self._listening = False
 
 
 def _span_id(trace_id: bytes, index: int) -> bytes:
@@ -373,7 +502,6 @@ class SelfTracer:
         self.traces_started = 0
         self.traces_exported = 0
         self.spans_exported = 0
-        self.links_exported = 0
 
     def flush_segment(self, phases: dict) -> None:
         """Record one decode-pool flush's phase durations (worker
@@ -404,7 +532,6 @@ class SelfTracer:
         body = encode_selftrace_request(trace, self.service)
         self.traces_exported += 1
         self.spans_exported += len(trace.spans) + 1  # + root
-        self.links_exported += sum(len(s[4]) for s in trace.spans)
         if self._submit is not None:
             self._submit(body)
         return body
@@ -415,6 +542,5 @@ class SelfTracer:
             "traces_started": self.traces_started,
             "traces_exported": self.traces_exported,
             "spans_exported": self.spans_exported,
-            "links_exported": self.links_exported,
             "segments_pending": len(self._segments),
         }

@@ -41,9 +41,10 @@ chunk rows), threaded daemon → compose → k8s like every family.
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 
+from . import selftrace
+from .selftrace import SPAN_PUT, SPAN_STAGE
 from .tensorize import SpanColumns, SpanTensorizer, TensorBatch
 
 
@@ -57,31 +58,30 @@ class StagedBatch:
     arrays out once the stager's put has been issued.
 
     ``trace`` carries the batch's sampled self-trace (runtime.selftrace
-    BatchTrace, or None) across the stage→take hand-off; ``stage_dur``
-    / ``wait_s`` are this batch's OWN pack+put-issue and take-side
-    put-wait seconds — the per-batch samples behind the
+    BatchTrace, or None) across the stage→take hand-off; the stage and
+    put-wait spans append to it and observe the
     anomaly_phase_seconds{phase="stage"} and
-    anomaly_spine_put_wait_seconds histograms (the cumulative
-    ``stage_s``/``take_wait_s`` pool stats stay for the benches)."""
+    anomaly_spine_put_wait_seconds histograms. ``t_take`` is the pump's stamp when it took the rows off its queue
+    (``selftrace.clock``): the start of the batch's ring wait."""
 
     __slots__ = (
-        "cols", "width", "t_now", "t_oldest", "batch", "error", "ready",
-        "trace", "stage_dur", "wait_s",
+        "cols", "width", "t_now", "t_oldest", "t_take", "batch", "error",
+        "ready", "trace",
     )
 
     def __init__(
-        self, cols: SpanColumns, width: int, t_now, t_oldest, trace=None
+        self, cols: SpanColumns, width: int, t_now, t_oldest, t_take=None,
+        trace=None,
     ):
         self.cols = cols
         self.width = width
         self.t_now = t_now
         self.t_oldest = t_oldest
+        self.t_take = t_take
         self.batch: TensorBatch | None = None  # device arrays
         self.error: BaseException | None = None
         self.ready = threading.Event()
         self.trace = trace
-        self.stage_dur = 0.0
-        self.wait_s = 0.0
 
 
 class DevicePutSpine:
@@ -94,6 +94,7 @@ class DevicePutSpine:
         overlap: bool = True,
         chunk_rows: int = 0,
         device_put=None,
+        phase_observe=None,
     ):
         if depth < 1:
             raise ValueError(f"spine ring depth must be >= 1 (got {depth})")
@@ -102,6 +103,9 @@ class DevicePutSpine:
         self.overlap = bool(overlap)
         self.chunk_rows = int(chunk_rows)
         self._device_put = device_put
+        # The stage and put-wait spans observe their phases here (the
+        # pipeline's ``phase_observe`` hook, or None).
+        self.phase_observe = phase_observe
         # Ring slots: per-slot {width: host TensorBatch} (the adaptive
         # controller moves along a pow2 width ladder; each width's
         # buffers are allocated once and reused).
@@ -121,8 +125,6 @@ class DevicePutSpine:
         self.puts_total = 0
         self.overlap_hits = 0  # take() found the put already complete
         self.overlap_misses = 0  # take() had to wait on the put
-        self.stage_s = 0.0  # stager: pack + put issue + slot wait
-        self.take_wait_s = 0.0  # pump: time blocked in waiting takes
         self._thread = threading.Thread(
             target=self._run, name="spine-stager", daemon=True
         )
@@ -131,14 +133,17 @@ class DevicePutSpine:
     # -- pump-thread API ----------------------------------------------
 
     def stage(
-        self, cols: SpanColumns, width: int, t_now, t_oldest, trace=None
+        self, cols: SpanColumns, width: int, t_now, t_oldest, t_take=None,
+        trace=None,
     ) -> None:
         """Enqueue one assembled batch for pack+put (never blocks —
         the PUMP enforces the ring bound by wait-dispatching the head
         before staging past ``depth``; the pump thread is the spine's
         only consumer, so blocking here would deadlock it against
         itself). ``trace`` rides the StagedBatch to dispatch."""
-        staged = StagedBatch(cols, int(width), t_now, t_oldest, trace=trace)
+        staged = StagedBatch(
+            cols, int(width), t_now, t_oldest, t_take, trace=trace
+        )
         with self._work:
             if self._stop:
                 raise SpineError("spine is closed")
@@ -157,21 +162,20 @@ class DevicePutSpine:
             staged = self._staged[0] if self._staged else None
         if staged is None:
             return None
-        if staged.ready.is_set():
-            hit = True
-        elif not wait:
+        hit = staged.ready.is_set()
+        if not hit and not wait:
             return None
-        else:
-            hit = False
-            t0 = time.perf_counter()
-            if not staged.ready.wait(timeout):
+        # The put-wait span: ~0 on a hit (the put finished behind the
+        # in-flight step), the blocked wait on a miss.
+        with selftrace.span(
+            SPAN_PUT, observe=self.phase_observe, trace=staged.trace,
+            attrs=(("overlap.hit", str(int(hit))),),
+        ):
+            if not hit and not staged.ready.wait(timeout):
                 raise SpineError(
                     f"staged batch not ready after {timeout}s "
                     "(stager dead or device put wedged)"
                 )
-            staged.wait_s = time.perf_counter() - t0
-            with self._lock:
-                self.take_wait_s += staged.wait_s
         with self._work:
             # Still the head (single consumer — the pump thread).
             if self._staged and self._staged[0] is staged:
@@ -227,7 +231,6 @@ class DevicePutSpine:
             taken = hits + misses
             return {
                 "ring_depth": self.depth,
-                "staged": len(self._staged),
                 "puts_total": puts,
                 "overlap_hits": hits,
                 "overlap_misses": misses,
@@ -235,8 +238,6 @@ class DevicePutSpine:
                 # host→device put completed entirely behind the
                 # in-flight step — transfer hidden by compute.
                 "overlap_ratio": (hits / taken) if taken else 0.0,
-                "stage_s": self.stage_s,
-                "take_wait_s": self.take_wait_s,
             }
 
     # -- stager thread -------------------------------------------------
@@ -256,6 +257,26 @@ class DevicePutSpine:
 
         return TensorBatch(*(jax.device_put(a) for a in host))
 
+    def _pack_and_put(self, staged: StagedBatch) -> TensorBatch:
+        idx = self._seq % self.depth
+        self._seq += 1
+        prev = self._slot_prev[idx]
+        if prev is not None:
+            # Double-buffer guard: never repack host memory a previous
+            # put may still be reading. block_until_ready on PUT arrays
+            # waits for the transfer only (they are inputs, not
+            # computation results).
+            import jax
+
+            jax.block_until_ready(tuple(prev))
+        slot = self._host_slot(idx, staged.width)
+        host = self.tensorizer.pack_columns_into(
+            slot, staged.cols, chunk_rows=self.chunk_rows
+        )
+        dev = self._put(host)
+        self._slot_prev[idx] = dev
+        return dev
+
     def _run(self) -> None:
         while True:
             with self._work:
@@ -270,30 +291,17 @@ class DevicePutSpine:
                     self._jobs.clear()
                     return
                 staged = self._jobs.popleft()
-            t0 = time.perf_counter()
             try:
-                idx = self._seq % self.depth
-                self._seq += 1
-                prev = self._slot_prev[idx]
-                if prev is not None:
-                    # Double-buffer guard: never repack host memory a
-                    # previous put may still be reading. block_until_
-                    # ready on PUT arrays waits for the transfer only
-                    # (they are inputs, not computation results).
-                    import jax
-
-                    jax.block_until_ready(tuple(prev))
-                slot = self._host_slot(idx, staged.width)
-                host = self.tensorizer.pack_columns_into(
-                    slot, staged.cols, chunk_rows=self.chunk_rows
-                )
-                dev = self._put(host)
-                self._slot_prev[idx] = dev
-                staged.batch = dev
-                staged.stage_dur = time.perf_counter() - t0
+                # The stage span (stager thread): pack + put issue +
+                # slot wait. The batch's trace is this thread's until
+                # ready.set() hands the batch to the pump.
+                with selftrace.span(
+                    SPAN_STAGE, observe=self.phase_observe,
+                    trace=staged.trace,
+                ):
+                    staged.batch = self._pack_and_put(staged)
                 with self._lock:
                     self.puts_total += 1
-                    self.stage_s += staged.stage_dur
             except Exception as e:  # noqa: BLE001 — surfaced via
                 # staged.error to the taking dispatcher; the stager
                 # thread itself must survive (it is the only producer

@@ -35,6 +35,9 @@ class MetricRegistry:
         self._counters: dict[tuple[str, tuple], float] = {}
         self._gauges: dict[tuple[str, tuple], float] = {}
         self._help: dict[str, str] = {}
+        # (name, labels, buckets) -> a histogram's series keys, bounded
+        # by the label sets the counters themselves hold.
+        self._histo_keys: dict[tuple, tuple] = {}
 
     def describe(self, name: str, help_text: str) -> None:
         self._help[name] = help_text
@@ -64,9 +67,29 @@ class MetricRegistry:
         the Prometheus side (ValkeyCartStore.cs:30-43) and the shape the
         spanmetrics connector's duration histograms export.
         """
-        # One sort per observation (this runs per span in the
-        # spanmetrics hot path); bucket keys splice in the "le" pair.
-        base = sorted(labels.items())
+        # This runs per span in the spanmetrics hot path and per phase
+        # on the detector's served path: the series keys of one
+        # (name, labels, buckets) are built once and reused.
+        base_key = tuple(sorted(labels.items()))
+        if not isinstance(buckets, tuple):
+            buckets = tuple(buckets)
+        keys = self._histo_keys.get((name, base_key, buckets))
+        if keys is None:
+            keys = self._histo_series(name, base_key, buckets)
+        bucket_keys, inf_key, sum_key, count_key = keys
+        counters = self._counters
+        with self._lock:
+            for le, key in zip(buckets, bucket_keys):
+                if value <= le:
+                    counters[key] = counters.get(key, 0.0) + 1.0
+            counters[inf_key] = counters.get(inf_key, 0.0) + 1.0
+            counters[sum_key] = counters.get(sum_key, 0.0) + value
+            counters[count_key] = counters.get(count_key, 0.0) + 1.0
+
+    def _histo_series(self, name: str, base_key: tuple, buckets: tuple) -> tuple:
+        """The ``_bucket`` keys (one per bound), the ``+Inf`` bucket,
+        ``_sum`` and ``_count`` keys of one histogram series."""
+        base = list(base_key)
         i = 0
         while i < len(base) and base[i][0] < "le":
             i += 1
@@ -74,18 +97,14 @@ class MetricRegistry:
         def with_le(le_str: str) -> tuple:
             return tuple(base[:i] + [("le", le_str)] + base[i:])
 
-        base_key = tuple(base)
-        with self._lock:
-            for le in buckets:
-                if value <= le:
-                    key = (name + "_bucket", with_le(_fmt_le(le)))
-                    self._counters[key] = self._counters.get(key, 0.0) + 1.0
-            key = (name + "_bucket", with_le("+Inf"))
-            self._counters[key] = self._counters.get(key, 0.0) + 1.0
-            key = (name + "_sum", base_key)
-            self._counters[key] = self._counters.get(key, 0.0) + value
-            key = (name + "_count", base_key)
-            self._counters[key] = self._counters.get(key, 0.0) + 1.0
+        keys = (
+            [(name + "_bucket", with_le(_fmt_le(le))) for le in buckets],
+            (name + "_bucket", with_le("+Inf")),
+            (name + "_sum", base_key),
+            (name + "_count", base_key),
+        )
+        self._histo_keys[(name, base_key, buckets)] = keys
+        return keys
 
     def snapshot(self) -> tuple[dict, dict]:
         """Point-in-time copy of (counters, gauges) — the scrape surface
@@ -187,6 +206,12 @@ ANOMALY_Z_SCORE = "app_anomaly_z_score"
 ANOMALY_CARDINALITY = "app_anomaly_distinct_traces"
 ANOMALY_HEAVY_HITTER = "app_anomaly_heavy_hitter_ratio"
 ANOMALY_SPANS_TOTAL = "app_anomaly_spans_processed_total"
+# The exact verdict counter: rows whose batch's report was harvested
+# (the processed counter advances at dispatch, up to two batches ahead).
+ANOMALY_SPANS_HARVESTED = "app_anomaly_spans_harvested_total"
+# Reports dropped unfetched (their batches still folded into state):
+# the in-flight bound, or a harvest interval, superseded them.
+ANOMALY_REPORTS_SKIPPED = "anomaly_reports_skipped_total"
 ANOMALY_LAG_P99 = "app_anomaly_detection_lag_p99_ms"
 ANOMALY_CUSUM = "app_anomaly_cusum"
 # The metrics-ingestion leg (OTLP /v1/metrics → metrics head).
@@ -233,6 +258,7 @@ ANOMALY_INGEST_POOL_FLUSHES = "anomaly_ingest_pool_flushes_total"
 ANOMALY_INGEST_POOL_SPANS = "anomaly_ingest_pool_spans_total"
 ANOMALY_INGEST_POOL_REQUESTS = "anomaly_ingest_pool_requests_total"
 ANOMALY_INGEST_POOL_UTILIZATION = "anomaly_ingest_pool_worker_utilization"
+ANOMALY_INGEST_POOL_BUSY = "anomaly_ingest_pool_busy_seconds_total"  # summed over workers
 # Device-put spine (runtime.spine: the staging ring between batch
 # assembly and the donated device step): whether host→device transfer
 # is actually hidden behind compute, and how deep the ring runs.
@@ -374,6 +400,11 @@ ANOMALY_BUILD_INFO = "anomaly_build_info"
 # outcome="error" means that width's step did not compile (the daemon
 # stays up and would pay the failure again at escalation time).
 ANOMALY_WIDTH_WARMUPS = "anomaly_width_warmups_total"  # {width=, outcome=}
+# The device's own counts, on the 1 s cadence: backend compiles since
+# boot (a compile while serving is a shape off the warmed ladder) and
+# device memory where the backend reports it.
+ANOMALY_COMPILES = "anomaly_compiles_total"
+ANOMALY_DEVICE_MEMORY = "anomaly_device_memory_bytes"  # {kind=in_use|peak}
 
 # Key lifecycle plane (runtime.keyspace): the budget watchdog's RSS
 # sample (first-class — the soak bench's VmRSS read, promoted to a
