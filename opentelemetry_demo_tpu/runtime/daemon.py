@@ -520,6 +520,7 @@ class DetectorDaemon:
         )
         self.registry.counter_add(tele_metrics.ANOMALY_COMPILES, 0.0)
         self.registry.counter_add(tele_metrics.ANOMALY_REPORTS_SKIPPED, 0.0)
+        self.registry.counter_add(tele_metrics.ANOMALY_SPINE_IDLE_WAITS, 0.0)
         self.registry.describe(
             tele_metrics.ANOMALY_BUILD_INFO,
             "Constant 1 labelled with package/frame/jax versions and "
@@ -612,6 +613,12 @@ class DetectorDaemon:
         self.registry.describe(
             tele_metrics.ANOMALY_SPINE_RING_DEPTH,
             "Configured device-put staging ring depth (0 = spine off)",
+        )
+        self.registry.describe(
+            tele_metrics.ANOMALY_SPINE_IDLE_WAITS,
+            "Staged batches taken with the device idle: the pump waited "
+            "for the put and dispatched in the same pass (not counted "
+            "in the overlap ratio)",
         )
         self.registry.describe(
             tele_metrics.ANOMALY_ROLE,
@@ -2976,7 +2983,8 @@ class DetectorDaemon:
 
     def _export_spine_stats(self) -> None:
         """anomaly_spine_* gauges: is the host→device transfer actually
-        hidden behind compute (overlap ratio), at what ring depth."""
+        hidden behind compute (overlap ratio), at what ring depth, and
+        how many batches were taken with the device idle instead."""
         st = self.pipeline.spine_stats()
         if st is None:
             self.registry.gauge_set(
@@ -2986,9 +2994,17 @@ class DetectorDaemon:
         self.registry.gauge_set(
             tele_metrics.ANOMALY_SPINE_RING_DEPTH, float(st["ring_depth"])
         )
-        self.registry.gauge_set(
-            tele_metrics.ANOMALY_SPINE_PUT_OVERLAP,
-            float(st["overlap_ratio"]),
+        if st["overlap_hits"] + st["overlap_misses"]:
+            # Absent until a batch is taken while a step runs: with the
+            # device always idle there is no overlap to report, and a
+            # 0 would read as "puts not hidden".
+            self.registry.gauge_set(
+                tele_metrics.ANOMALY_SPINE_PUT_OVERLAP,
+                float(st["overlap_ratio"]),
+            )
+        self._selftrace_delta(
+            tele_metrics.ANOMALY_SPINE_IDLE_WAITS, "spine_idle_waits",
+            st["idle_waits"],
         )
         # Histogram companion on a per-window basis: the lifetime
         # gauge flattens transients; one observation per scrape window

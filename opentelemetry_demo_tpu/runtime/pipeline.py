@@ -867,8 +867,9 @@ class DetectorPipeline:
             if self._spine is not None:
                 # Spine path: hand the columns to the stager (pack +
                 # async device put off the pump thread) and dispatch
-                # whatever staged batch is ready — typically the one
-                # whose transfer just overlapped the in-flight step.
+                # the head of the ring: with the device idle, the batch
+                # just staged once its put is issued; with a step
+                # running, one whose transfer already rode behind it.
                 # Ring bound first: past `depth` undispatched batches
                 # the pump wait-dispatches the head — the ring IS the
                 # backpressure, and the pump is the only consumer.
@@ -952,25 +953,42 @@ class DetectorPipeline:
                 self.stats.reports_skipped += 1
                 self._note_outcome(skipped=True)
 
+    def _device_idle(self) -> bool:
+        """True when no step is running on the device: nothing is in
+        flight, or the newest in-flight report is done (``is_ready()``,
+        a non-blocking check on a ``jax.Array``; steps finish in
+        dispatch order, so the newest being done means all are). An
+        unharvested report is no running step. A report stand-in
+        without ``is_ready`` (host values in tests) reads as finished."""
+        with self._inflight_lock:
+            if not self._inflight:
+                return True
+            report = self._inflight[-1][2]
+        is_ready = getattr(report, "is_ready", None)
+        return is_ready is None or bool(is_ready())
+
     def _pump_spine(self, force_wait: bool = False) -> bool:
         """Dispatch the oldest staged batch if available (spine path).
 
-        Overlap discipline: with a step already in flight the pump
-        takes only a batch whose put has COMPLETED (a not-ready batch
-        dispatches next tick — its transfer keeps riding behind the
-        running step, which is the whole point); with the device idle,
-        under drain, at the ring bound, or with overlap disabled it
-        waits — the low-rate regime must not defer a lone batch a
-        whole pump interval."""
-        with self._inflight_lock:
-            idle = not self._inflight
+        Overlap discipline, decided by asking the device whether a step
+        is still running (``_device_idle``): with a step running the
+        pump takes only a batch whose put has COMPLETED (a not-ready
+        batch dispatches on a later pump — its transfer keeps riding
+        behind the running step, which is the whole point); with the
+        device idle, under drain, at the ring bound, or with overlap
+        disabled it waits. So a batch staged with the device idle
+        dispatches in the pump that staged it: it waits for its pack
+        and put, never a whole pump interval."""
+        if not self._spine.pending():
+            return False  # an empty ring asks the device nothing
+        idle = self._device_idle()
         must_wait = (
             force_wait
             or not self._spine.overlap
             or self._harvest_flush
             or idle
         )
-        staged = self._spine.take(wait=must_wait)
+        staged = self._spine.take(wait=must_wait, idle=idle)
         if staged is None:
             return False
         # n_valid from the host row count: the device batch's own

@@ -15,11 +15,17 @@ working through a small ring of pre-allocated host staging buffers:
   every lane. ``device_put`` is asynchronous on real accelerators, so
   the transfer of batch *k+1* rides the wire WHILE the device executes
   batch *k*'s donated step — the overlap the e2e SLO measures.
-- ``take(wait=...)`` (pump thread) pops the oldest staged batch. With
-  a step in flight the pump takes only batches whose put already
-  completed (``overlap_hits``); with the device idle — or under
-  ``drain()`` — it waits (``overlap_misses``), so the low-rate regime
-  pays no added latency beyond the put itself.
+- ``take(wait=..., idle=...)`` (pump thread) pops the oldest staged
+  batch. The pump asks the device whether a step is still running
+  (the newest in-flight report's ``is_ready()``), not whether a report
+  is still unharvested. With a step running it takes only a batch
+  whose put already completed (``overlap_hits``; the rest dispatch on a
+  later pump, their transfer riding behind the step), and it waits
+  only at the ring bound, under ``drain()`` or with overlap off
+  (``overlap_misses``). With the device idle it waits for the batch it
+  just staged and dispatches it in the same pass (``idle_waits``): a
+  batch waits behind a running step, never behind an idle device, and
+  the low-rate regime pays the pack and put alone.
 - **Double-buffer discipline**: a ring slot is repacked only after the
   device arrays created from its PREVIOUS use are ready
   (``jax.block_until_ready`` — i.e. the transfer consumed the host
@@ -123,8 +129,11 @@ class DevicePutSpine:
         self._stop = False
         # Stats (read by the daemon's scrape via stats()).
         self.puts_total = 0
-        self.overlap_hits = 0  # take() found the put already complete
-        self.overlap_misses = 0  # take() had to wait on the put
+        # Takes made while a step ran: the put already complete (hit)
+        # or waited on (miss). Takes with the device idle count apart.
+        self.overlap_hits = 0
+        self.overlap_misses = 0
+        self.idle_waits = 0
         self._thread = threading.Thread(
             target=self._run, name="spine-stager", daemon=True
         )
@@ -152,12 +161,14 @@ class DevicePutSpine:
             self._work.notify_all()
 
     def take(
-        self, wait: bool, timeout: float = 30.0
+        self, wait: bool, timeout: float = 30.0, idle: bool = False
     ) -> StagedBatch | None:
         """Oldest staged batch, device-resident — or None when nothing
         is ready and ``wait`` is False (the overlap regime: the pump
         dispatches it next tick, after the put finished behind the
-        in-flight step)."""
+        in-flight step). ``idle`` says no step is running on the
+        device: the take counts as an idle wait, not as an overlap hit
+        or miss."""
         with self._lock:
             staged = self._staged[0] if self._staged else None
         if staged is None:
@@ -166,7 +177,7 @@ class DevicePutSpine:
         if not hit and not wait:
             return None
         # The put-wait span: ~0 on a hit (the put finished behind the
-        # in-flight step), the blocked wait on a miss.
+        # in-flight step), the blocked wait on a miss or an idle wait.
         with selftrace.span(
             SPAN_PUT, observe=self.phase_observe, trace=staged.trace,
             attrs=(("overlap.hit", str(int(hit))),),
@@ -180,7 +191,9 @@ class DevicePutSpine:
             # Still the head (single consumer — the pump thread).
             if self._staged and self._staged[0] is staged:
                 self._staged.popleft()
-            if hit:
+            if idle:
+                self.idle_waits += 1
+            elif hit:
                 self.overlap_hits += 1
             else:
                 self.overlap_misses += 1
@@ -234,9 +247,10 @@ class DevicePutSpine:
                 "puts_total": puts,
                 "overlap_hits": hits,
                 "overlap_misses": misses,
-                # Of the batches dispatched so far, the fraction whose
-                # host→device put completed entirely behind the
-                # in-flight step — transfer hidden by compute.
+                "idle_waits": self.idle_waits,
+                # Of the batches taken while a step ran, the fraction
+                # whose host→device put completed entirely behind it —
+                # transfer hidden by compute.
                 "overlap_ratio": (hits / taken) if taken else 0.0,
             }
 

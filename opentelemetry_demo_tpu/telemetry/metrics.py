@@ -264,6 +264,9 @@ ANOMALY_INGEST_POOL_BUSY = "anomaly_ingest_pool_busy_seconds_total"  # summed ov
 # is actually hidden behind compute, and how deep the ring runs.
 ANOMALY_SPINE_PUT_OVERLAP = "anomaly_spine_put_overlap_ratio"
 ANOMALY_SPINE_RING_DEPTH = "anomaly_spine_ring_depth"
+# Staged batches the pump waited for with the device idle and
+# dispatched in the pass that staged them (outside the overlap ratio).
+ANOMALY_SPINE_IDLE_WAITS = "anomaly_spine_idle_waits_total"
 # Sender-queue visibility for the OTLP exporters (otlp_export.py):
 # the drop-oldest path and its backlog, per signal.
 ANOMALY_EXPORT_DROPPED = "anomaly_export_dropped_total"  # {signal=}

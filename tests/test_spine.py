@@ -16,6 +16,11 @@ What must hold (the r9 spine tentpole):
 - **Ring discipline** (``test_ring_slots_are_reused``): slot buffers
   are allocated once per (slot, width) and reused — the staging pack
   performs zero width-sized allocations in steady state.
+- **Overlap discipline** (``TestIdleDevice``): the pump asks the
+  device whether a step is running. With the device idle it waits for
+  the batch it just staged and dispatches it in the same pump (an idle
+  wait); with a step running it never blocks on a put, and the batch
+  dispatches on a later pump once its put is done (an overlap hit).
 - **Lifecycle** (``test_drain_flushes_staged_batches``, flag-off drop,
   knob validation): nothing staged is ever lost on drain, the off
   switch drops staged rows with the queue, and malformed spine knobs
@@ -24,7 +29,9 @@ What must hold (the r9 spine tentpole):
 
 from __future__ import annotations
 
+import itertools
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -42,6 +49,39 @@ from opentelemetry_demo_tpu.utils.config import ConfigError, spine_config
 pytestmark = pytest.mark.spine
 
 SMALL = dict(num_services=8, hll_p=8, cms_width=512)
+
+
+class _StepReport:
+    """Report stand-in: the real report array, whose step reads as
+    running while ``running()`` is True (a CPU step finishes before the
+    next pump, so a running step has to be made observable)."""
+
+    def __init__(self, arr, running):
+        self._arr = arr
+        self._running = running
+
+    def is_ready(self) -> bool:
+        return not self._running() and self._arr.is_ready()
+
+    def copy_to_host_async(self) -> None:
+        self._arr.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._arr, dtype=dtype)
+
+
+def _report_steps(det, running) -> None:
+    """Wrap ``det.observe_packed`` so each report is a ``_StepReport``;
+    ``running(n)`` says whether the n-th dispatched step reads as
+    still running."""
+    observe = det.observe_packed
+    count = itertools.count()
+
+    def wrapped(batch, t_now):
+        n = next(count)
+        return _StepReport(observe(batch, t_now), lambda: running(n))
+
+    det.observe_packed = wrapped
 
 
 def _run_stream(spine_ring: int, n_batches: int = 40, seed: int = 7):
@@ -173,6 +213,10 @@ class TestDonationSafety:
             batch_size=256,
             spine_ring=2,
         )
+        # Odd steps read as still running at the next pump (a CPU step
+        # finishes before it), so the overlap branch stays engaged;
+        # even ones finish, so idle waits interleave with it.
+        _report_steps(det, lambda n: n % 2 == 1)
         rng = np.random.default_rng(11)
         stop = threading.Event()
         failures: list[str] = []
@@ -216,10 +260,120 @@ class TestDonationSafety:
         assert st["puts_total"] == pipe.stats.batches == 300
         # The hammer must actually have exercised the overlap regime.
         assert st["overlap_hits"] > 0
+        assert st["idle_waits"] > 0
         # Every harvested report is finite — a scribbled staging slot
         # would surface as garbage z-scores long before a crash.
         for rep in harvested:
             assert np.isfinite(np.asarray(rep.lat_z)).all()
+
+
+class TestIdleDevice:
+    @staticmethod
+    def _pipe(det, put, on_report=None):
+        pipe = DetectorPipeline(
+            det, on_report=on_report, batch_size=128, spine_ring=2
+        )
+        # No harvest falls due: the reports stay in flight, so the pump
+        # has to tell a finished step from a running one.
+        pipe.harvest_interval_s = 3600.0
+        pipe._spine._device_put = put
+        return pipe
+
+    def test_idle_device_dispatches_in_the_pump_that_staged(self):
+        def slow_put(a):
+            time.sleep(0.005)  # the put outlasts the pump's own pass
+            return jax.device_put(a)
+
+        det = AnomalyDetector(DetectorConfig(**SMALL))
+        pipe = self._pipe(det, slow_put)
+        rng = np.random.default_rng(8)
+        try:
+            for k in (1, 2, 3):
+                pipe.submit_columns(make_columns(rng, 100))
+                pipe.pump(0.05 * k)
+                # Dispatched within the call that staged it.
+                assert pipe.stats.batches == k
+                assert pipe._spine.pending() == 0
+                st = pipe.spine_stats()
+                assert st["idle_waits"] == k
+                assert st["overlap_hits"] == st["overlap_misses"] == 0
+                # The previous step finishes before the next pump, its
+                # report still unharvested in flight.
+                jax.block_until_ready(pipe._inflight[-1][2])
+            assert len(pipe._inflight) == 2  # the in-flight bound
+        finally:
+            pipe.close()
+        assert pipe.stats.spans == 300
+
+    def test_running_step_never_blocks_the_pump_on_a_put(self):
+        gate = threading.Event()
+        gate.set()
+
+        def gated_put(a):
+            gate.wait(10.0)
+            return jax.device_put(a)
+
+        finished = threading.Event()
+        det = AnomalyDetector(DetectorConfig(**SMALL))
+        _report_steps(det, lambda n: not finished.is_set())
+        times = []
+        pipe = self._pipe(
+            det, gated_put, on_report=lambda t, r, f: times.append(t)
+        )
+        rng = np.random.default_rng(9)
+        try:
+            pipe.submit_columns(make_columns(rng, 100))
+            pipe.pump(0.05)  # nothing in flight: an idle wait
+            assert pipe.stats.batches == 1
+            gate.clear()  # the next put wedges while the step runs
+            pipe.submit_columns(make_columns(rng, 100))
+            t0 = time.monotonic()
+            pipe.pump(0.10)
+            assert time.monotonic() - t0 < 5.0  # did not wait on the put
+            assert pipe.stats.batches == 1
+            assert pipe._spine.pending() == 1
+            gate.set()
+            staged = pipe._spine._staged[0]
+            assert staged.ready.wait(10.0)
+            pipe.pump(0.15)  # the step still runs; the put is done
+            assert pipe.stats.batches == 2
+            assert pipe._spine.pending() == 0
+            st = pipe.spine_stats()
+            assert st["overlap_hits"] == 1 and st["overlap_misses"] == 0
+            assert st["idle_waits"] == 1 and st["overlap_ratio"] == 1.0
+        finally:
+            gate.set()
+            finished.set()
+            pipe.close()
+        # Each batch keeps the t_now of the pump that staged it.
+        assert times == [0.05, 0.10]
+        assert pipe.stats.spans == 200
+
+    def test_daemon_exports_idle_waits_apart_from_the_overlap_ratio(
+        self, monkeypatch, tmp_path
+    ):
+        from opentelemetry_demo_tpu.runtime.daemon import DetectorDaemon
+        from opentelemetry_demo_tpu.telemetry import metrics as tm
+
+        from .test_selftrace import _daemon_env
+
+        _daemon_env(monkeypatch, tmp_path, ANOMALY_SPINE_RING="2")
+        daemon = DetectorDaemon(DetectorConfig(**SMALL))
+        try:
+            pipe = daemon.pipeline
+            rng = np.random.default_rng(10)
+            for i in range(3):
+                pipe.submit_columns(make_columns(rng, 50))
+                pipe.pump(float(i))
+            daemon._export_spine_stats()
+            counters, gauges = daemon.registry.snapshot()
+            assert pipe.stats.batches == 3
+            assert counters[(tm.ANOMALY_SPINE_IDLE_WAITS, ())] == 3.0
+            # No take was made while a step ran: no ratio to report.
+            assert (tm.ANOMALY_SPINE_PUT_OVERLAP, ()) not in gauges
+            assert gauges[(tm.ANOMALY_SPINE_RING_DEPTH, ())] == 2.0
+        finally:
+            daemon.shutdown()
 
 
 class TestLifecycle:
