@@ -35,8 +35,9 @@ pass):
   (default 1 = on): widens batches in pow2 steps when report readback
   can't keep pace, bounding the skip rate under load spikes; set 0 for
   a fixed width. The width ladder precompiles in the background at boot
-- ``ANOMALY_PUMP_INTERVAL_S``  batch cadence (default 0.05 — the <100ms
-                               detection-lag budget spends half on batching)
+- ``ANOMALY_PUMP_INTERVAL_S``  longest serve-loop sleep with no rows
+                               arriving (default 0.05); admitted rows
+                               wake the loop at once
 - ``FLAGD_FILE``             flagd-schema JSON path (hot-reloaded)
 - ``OFREP_URL``              OFREP endpoint (used when FLAGD_FILE unset)
 - ``KAFKA_ADDR``             bootstrap servers for the orders topic
@@ -521,6 +522,10 @@ class DetectorDaemon:
         self.registry.counter_add(tele_metrics.ANOMALY_COMPILES, 0.0)
         self.registry.counter_add(tele_metrics.ANOMALY_REPORTS_SKIPPED, 0.0)
         self.registry.counter_add(tele_metrics.ANOMALY_SPINE_IDLE_WAITS, 0.0)
+        for cause in ("rows", "interval"):
+            self.registry.counter_add(
+                tele_metrics.ANOMALY_PUMP_WAKES, 0.0, cause=cause
+            )
         self.registry.describe(
             tele_metrics.ANOMALY_BUILD_INFO,
             "Constant 1 labelled with package/frame/jax versions and "
@@ -619,6 +624,12 @@ class DetectorDaemon:
             "Staged batches taken with the device idle: the pump waited "
             "for the put and dispatched in the same pass (not counted "
             "in the overlap ratio)",
+        )
+        self.registry.describe(
+            tele_metrics.ANOMALY_PUMP_WAKES,
+            "Serve-loop passes by what ended the loop's wait: rows "
+            "(admitted rows woke it) or interval (the pump interval "
+            "elapsed with none)",
         )
         self.registry.describe(
             tele_metrics.ANOMALY_ROLE,
@@ -3837,15 +3848,31 @@ class DetectorDaemon:
         self.start()
         if on_ready is not None:
             on_ready(self)
+        wake = self.pipeline.wake
         try:
             while True:
-                # The pump's sleep, named on the profiler's host plane
+                # The loop's wait, named on the profiler's host plane
                 # so the device's idle time between passes reads as
-                # waiting, not as unexplained.
+                # waiting, not as unexplained. A role that pumps spans
+                # wakes on the first of new rows (the pipeline's wake,
+                # set by each admission, cleared here before the pass)
+                # and the interval: rows that land during a pass go in
+                # the next, so the pass bounds the cadence, and rows
+                # held pending set nothing. Standby and promoting pump
+                # no spans and keep the fixed wait.
                 with selftrace.span(selftrace.SPAN_LOOP_WAIT):
-                    stopped = self._stop.wait(self.pump_interval_s)
-                if stopped:
+                    if self.role in (ROLE_STANDBY, ROLE_PROMOTING):
+                        self._stop.wait(self.pump_interval_s)
+                        woke = False
+                    else:
+                        woke = wake.wait(self.pump_interval_s)
+                        wake.clear()
+                if self._stop.is_set():
                     break
+                self.registry.counter_add(
+                    tele_metrics.ANOMALY_PUMP_WAKES, 1.0,
+                    cause="rows" if woke else "interval",
+                )
                 # Guarded: one bad step (a transient JAX/transport/
                 # filesystem fault) backs off and retries — the serve
                 # loop of an always-on sidecar must not be one
@@ -3863,6 +3890,7 @@ class DetectorDaemon:
 
     def stop(self) -> None:
         self._stop.set()
+        self.pipeline.wake.set()  # a loop waiting for rows sees it now
 
     def shutdown(self) -> None:
         if self.fleet is not None:

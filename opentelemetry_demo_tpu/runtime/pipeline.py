@@ -329,6 +329,11 @@ class DetectorPipeline:
         self._pending: deque = deque()
         self._pending_rows = 0
         self._pending_lock = threading.Lock()
+        # Set by every admission that queued rows; a serve loop waits
+        # on it (and clears it) to take new rows at once instead of at
+        # its next interval. Edge-triggered: rows the pump leaves
+        # pending on purpose (the adaptive hold) set nothing again.
+        self.wake = threading.Event()
         # Bounded admission (queue_max_rows > 0): the pending queue is
         # row-budgeted — the memory_limiter analogue for THIS process.
         # Above the budget the overflow shed drops the OLDEST OK-lane
@@ -487,6 +492,7 @@ class DetectorPipeline:
             if self.queue_max_rows and self._pending_rows > self.queue_max_rows:
                 self._shed_locked()
             rows = self._pending_rows
+        self.wake.set()
         self._admission_update(rows)
 
     # -- bounded admission / brownout ----------------------------------
@@ -841,10 +847,10 @@ class DetectorPipeline:
         # the low watermark must reopen the gate THIS pump, not next.
         self._admission_update(rows_after)
         if not parts:
-            # Nothing new to assemble — but a staged batch from an
-            # earlier pump may be ready now (its put rode behind the
-            # previous step): dispatch it before the idle harvest.
-            if self._spine is not None and self._pump_spine():
+            # Nothing new to assemble — but batches staged by an
+            # earlier pump may be ready now (their puts rode behind the
+            # previous step): dispatch them before the idle harvest.
+            if self._spine is not None and self._dispatch_staged():
                 pass
             else:
                 # Nothing to dispatch — but an idle pump must still
@@ -867,9 +873,9 @@ class DetectorPipeline:
             if self._spine is not None:
                 # Spine path: hand the columns to the stager (pack +
                 # async device put off the pump thread) and dispatch
-                # the head of the ring: with the device idle, the batch
-                # just staged once its put is issued; with a step
-                # running, one whose transfer already rode behind it.
+                # from the ring: with the device idle, every staged
+                # batch once its put is issued; with a step running,
+                # those whose transfer already rode behind it.
                 # Ring bound first: past `depth` undispatched batches
                 # the pump wait-dispatches the head — the ring IS the
                 # backpressure, and the pump is the only consumer.
@@ -878,7 +884,7 @@ class DetectorPipeline:
                 self._spine.stage(
                     cols, width, t_now, t_oldest, t_take, trace=trace
                 )
-                self._pump_spine()
+                self._dispatch_staged()
             else:
                 batch = self.tensorizer.pack_columns(cols, width=width)
                 self._dispatch_batch(
@@ -967,7 +973,25 @@ class DetectorPipeline:
         is_ready = getattr(report, "is_ready", None)
         return is_ready is None or bool(is_ready())
 
-    def _pump_spine(self, force_wait: bool = False) -> bool:
+    def _dispatch_staged(self) -> bool:
+        """Dispatch staged batches, oldest first, by one answer of
+        ``_device_idle`` asked before the first take: with the device
+        idle, every batch in the ring; with a step running, each whose
+        put is done. One take per pump would keep a batch left behind
+        (by a pump that met a running step) a pump period in the ring,
+        and every later one after it, while each pump also stages a
+        batch, as a serve loop woken by every arrival does."""
+        if not self._spine.pending():
+            return False  # an empty ring asks the device nothing
+        idle = self._device_idle()
+        took = False
+        while self._pump_spine(idle=idle):
+            took = True
+        return took
+
+    def _pump_spine(
+        self, force_wait: bool = False, idle: bool | None = None
+    ) -> bool:
         """Dispatch the oldest staged batch if available (spine path).
 
         Overlap discipline, decided by asking the device whether a step
@@ -981,7 +1005,8 @@ class DetectorPipeline:
         and put, never a whole pump interval."""
         if not self._spine.pending():
             return False  # an empty ring asks the device nothing
-        idle = self._device_idle()
+        if idle is None:
+            idle = self._device_idle()
         must_wait = (
             force_wait
             or not self._spine.overlap

@@ -22,10 +22,11 @@ working through a small ring of pre-allocated host staging buffers:
   whose put already completed (``overlap_hits``; the rest dispatch on a
   later pump, their transfer riding behind the step), and it waits
   only at the ring bound, under ``drain()`` or with overlap off
-  (``overlap_misses``). With the device idle it waits for the batch it
-  just staged and dispatches it in the same pass (``idle_waits``): a
-  batch waits behind a running step, never behind an idle device, and
-  the low-rate regime pays the pack and put alone.
+  (``overlap_misses``). With the device idle it waits for every staged
+  batch, the one it just staged included, and dispatches them in the
+  same pass (``idle_waits``): a batch waits behind a running step,
+  never behind an idle device, and the low-rate regime pays the pack
+  and put alone.
 - **Double-buffer discipline**: a ring slot is repacked only after the
   device arrays created from its PREVIOUS use are ready
   (``jax.block_until_ready`` — i.e. the transfer consumed the host

@@ -267,6 +267,9 @@ ANOMALY_SPINE_RING_DEPTH = "anomaly_spine_ring_depth"
 # Staged batches the pump waited for with the device idle and
 # dispatched in the pass that staged them (outside the overlap ratio).
 ANOMALY_SPINE_IDLE_WAITS = "anomaly_spine_idle_waits_total"
+# Serve-loop passes by what ended the loop's wait: admitted rows, or
+# the pump interval elapsing with none.
+ANOMALY_PUMP_WAKES = "anomaly_pump_wakes_total"  # {cause=rows|interval}
 # Sender-queue visibility for the OTLP exporters (otlp_export.py):
 # the drop-oldest path and its backlog, per signal.
 ANOMALY_EXPORT_DROPPED = "anomaly_export_dropped_total"  # {signal=}

@@ -328,8 +328,8 @@ DAEMON_KNOBS: dict[str, tuple[str, object, str]] = {
     ),
     "ANOMALY_PUMP_INTERVAL_S": (
         "float", 0.05,
-        "batch cadence seconds (the <100ms detection-lag budget "
-        "spends half on batching)",
+        "longest serve-loop sleep in seconds when no rows arrive "
+        "(admitted rows wake the loop at once)",
     ),
     "ANOMALY_HARVEST_INTERVAL": (
         "float", 0.0,

@@ -18,9 +18,10 @@ What must hold (the r9 spine tentpole):
   performs zero width-sized allocations in steady state.
 - **Overlap discipline** (``TestIdleDevice``): the pump asks the
   device whether a step is running. With the device idle it waits for
-  the batch it just staged and dispatches it in the same pump (an idle
-  wait); with a step running it never blocks on a put, and the batch
-  dispatches on a later pump once its put is done (an overlap hit).
+  every batch staged, the one it just staged included, and dispatches
+  them in the same pump (idle waits); with a step running it never
+  blocks on a put, and the batch dispatches on a later pump once its
+  put is done (an overlap hit).
 - **Lifecycle** (``test_drain_flushes_staged_batches``, flag-off drop,
   knob validation): nothing staged is ever lost on drain, the off
   switch drops staged rows with the queue, and malformed spine knobs
@@ -348,6 +349,47 @@ class TestIdleDevice:
         # Each batch keeps the t_now of the pump that staged it.
         assert times == [0.05, 0.10]
         assert pipe.stats.spans == 200
+
+    def test_a_batch_left_staged_goes_with_the_next_pump(self):
+        """A pump that meets a running step leaves its batch staged.
+        The next pump, which stages one of its own, finds the device
+        idle and dispatches both, oldest first: no batch waits a pump
+        period in the ring behind each later one."""
+        gate = threading.Event()
+        gate.set()
+
+        def gated_put(a):
+            gate.wait(10.0)
+            return jax.device_put(a)
+
+        finished = threading.Event()
+        det = AnomalyDetector(DetectorConfig(**SMALL))
+        _report_steps(det, lambda n: n == 0 and not finished.is_set())
+        pipe = self._pipe(det, gated_put)
+        rng = np.random.default_rng(11)
+        try:
+            pipe.submit_columns(make_columns(rng, 100))
+            pipe.pump(0.05)  # nothing in flight: an idle wait
+            gate.clear()  # step 0 runs and the next put is slow
+            pipe.submit_columns(make_columns(rng, 100))
+            pipe.pump(0.10)
+            assert pipe.stats.batches == 1
+            assert pipe._spine.pending() == 1
+            gate.set()
+            finished.set()
+            for k in (3, 4, 5):
+                # The previous step finishes before the next pump.
+                jax.block_until_ready(pipe._inflight[-1][2]._arr)
+                pipe.submit_columns(make_columns(rng, 100))
+                pipe.pump(0.05 * k)
+                assert pipe.stats.batches == k
+                assert pipe._spine.pending() == 0
+            assert pipe.spine_stats()["idle_waits"] == 5
+        finally:
+            gate.set()
+            finished.set()
+            pipe.close()
+        assert pipe.stats.spans == 500
 
     def test_daemon_exports_idle_waits_apart_from_the_overlap_ratio(
         self, monkeypatch, tmp_path
