@@ -1,17 +1,14 @@
-"""Benchmark: BOTH north stars of the flagship detector.
+"""Benchmark: the flagship detector's throughput north star.
 
-1. **Throughput** — sustained spans/sec through the full single-chip
-   detector update (HLL + CMS + EWMA heads + heavy-hitter query +
-   window rotation) on device-resident batches (BASELINE:
-   "≥200,000 spans/sec sketch updates on a single v5e-1").
-2. **Detection lag** — p99 of submit→report-harvest time through the
-   REAL DetectorPipeline at the default-Locust-profile rate (BASELINE:
-   "<100 ms p99 detection lag"), with the measured device→host fetch
-   time reported beside it.
+**Throughput** — sustained spans/sec through the full single-chip
+detector update (HLL + CMS + EWMA heads + heavy-hitter query + window
+rotation) on device-resident batches (BASELINE: "≥200,000 spans/sec
+sketch updates on a single v5e-1"). Detection lag is measured from the
+daemon's OTLP door by ``benchmark/run.py`` (``verdict_p95_ms``).
 
 Prints ONE JSON line:
     {"metric": ..., "value": N, "unit": "spans/sec", "vs_baseline": N,
-     "lag_p99_ms": N, "lag_vs_baseline": N, "fetch_rtt_ms": N, ...}
+     ...}
 
 Methodology: two state-chained regions of k1 and k2 steps, each
 terminated by a real device→host scalar fetch (the chain's final
@@ -46,7 +43,6 @@ from opentelemetry_demo_tpu.models import (
 from opentelemetry_demo_tpu.runtime import SpanTensorizer
 
 BASELINE_SPANS_PER_SEC = 200_000.0
-BASELINE_LAG_MS = 100.0
 # Host-ingest SLO (the r6 parallel-ingest tentpole): the r5 serial
 # native path measured 2.26M spans/s on its CI host — 53× under
 # the device rate it feeds. The pooled engine must clear 3× that.
@@ -370,7 +366,7 @@ def main():
     # Real HTTP query service hammered beside live ingest in one
     # process: query_p99_ms is the dashboard-refresh cost over live
     # sketches, query_qps the sustained read rate, ingest_ratio the
-    # "reads don't degrade the write path" guard (the ingest/lag SLOs
+    # "reads don't degrade the write path" guard (the ingest SLOs
     # above stay gated independently).
     queryq = {}
     if os.environ.get("BENCH_QUERY", "1") != "0":
@@ -379,10 +375,6 @@ def main():
         )
 
         queryq = measure_query()
-
-    # ---- north star #2: detection lag through the real pipeline ------
-    fetch_rtt_ms = measure_fetch_rtt()
-    lag = measure_lag(rng)
 
     # ---- detection quality: per-fault TTD + false-positive rate ------
     # Detector math is backend-independent; a CPU subprocess steps the
@@ -403,32 +395,8 @@ def main():
     if os.environ.get("BENCH_MITIG", "1") != "0":
         mitig = measure_mitigation_subprocess()
 
-    # ---- stress config (BASELINE #4: 10× the Locust profile) ---------
-    # Same methodology at 10× the rate with the async harvester (the
-    # stress deployment shape); paired-RTT fields ride along.
-    stress = {}
-    if os.environ.get("BENCH_LAG_STRESS", "1") != "0":
-        from opentelemetry_demo_tpu.runtime.lagbench import (
-            measure_lag as run_lag,
-        )
-
-        stress = run_lag(
-            rate=20_000.0, seconds=8.0, batch=1024, harvest_async=True,
-            # Adaptive width: under readback-RTT-bound harvest the
-            # controller widens batches until dispatch rate ≤ harvest
-            # rate — bounding the report skip rate the stress gate
-            # checks (r4 shipped 0.5 here; the gate wants <0.1).
-            adaptive=True,
-        )
-
     # ---- SLO verdicts (BASELINE.md:20-21) ----------------------------
-    # Explicit pass/fail so a reader never reconstructs the argument:
-    # throughput against the 200k/s star; lag on the NET basis (each
-    # sample's paired fetch probe subtracted, see lag_note); stress
-    # skip rate gated <0.1 (reports the operator actually sees under
-    # 10× load).
-    lag_net = lag.get("p99_net_ms")
-    stress_skip = stress.get("skip_rate")
+    # Explicit pass/fail so a reader never reconstructs the argument.
     # e2e verdict basis (the ISSUE's gate): min(host ingest, kernel AT
     # THE MATCHED geometry/batch) — the spine bench measures its own
     # device-only reference so the ratio compares like with like; the
@@ -456,13 +424,6 @@ def main():
     slo = {
         "north_star_throughput_ok": bool(
             spans_per_sec >= BASELINE_SPANS_PER_SEC
-        ),
-        "north_star_lag_ok": (
-            bool(lag_net < BASELINE_LAG_MS) if lag_net is not None else None
-        ),
-        "north_star_lag_basis": "net_of_paired_rtt",
-        "stress_skip_rate_ok": (
-            bool(stress_skip < 0.1) if stress_skip is not None else None
         ),
         # Host-ingest verdict: the pooled engine must sustain ≥3× the
         # r5 serial rate (6.78M spans/s). Same hardware-eligibility
@@ -570,29 +531,6 @@ def main():
                 "value": round(spans_per_sec, 1),
                 "unit": "spans/sec",
                 "vs_baseline": round(spans_per_sec / BASELINE_SPANS_PER_SEC, 3),
-                "lag_p99_ms": lag["p99_ms"],
-                "lag_vs_baseline": round(
-                    BASELINE_LAG_MS / max(lag["p99_ms"], 1e-9), 3
-                ),
-                "lag_p99_net_ms": lag.get("p99_net_ms"),
-                "lag_p50_net_ms": lag.get("p50_net_ms"),
-                "lag_net_vs_baseline": (
-                    round(BASELINE_LAG_MS / max(lag["p99_net_ms"], 1e-9), 3)
-                    if lag.get("p99_net_ms") is not None
-                    else None
-                ),
-                "lag_rtt_p50_ms": lag.get("rtt_p50_ms"),
-                "lag_rtt_p99_ms": lag.get("rtt_p99_ms"),
-                "lag_rtt_pairs": lag.get("rtt_pairs"),
-                "lag_rate_spans_per_sec": lag["rate"],
-                "lag_batches": lag["batches"],
-                "lag_stress_p99_ms": stress.get("p99_ms"),
-                "lag_stress_p99_net_ms": stress.get("p99_net_ms"),
-                "lag_stress_rate_spans_per_sec": stress.get("rate"),
-                "lag_stress_batches": stress.get("batches"),
-                "lag_stress_reports_skipped": stress.get("reports_skipped"),
-                "lag_stress_skip_rate": stress.get("skip_rate"),
-                "lag_stress_final_batch_width": stress.get("final_batch_width"),
                 **slo,
                 "ttd_s": {
                     name: entry.get("ttd_s")
@@ -603,7 +541,6 @@ def main():
                     "paymentFailure_ttd_by_rate"
                 ),
                 "detection_quality": quality or None,
-                "fetch_rtt_ms": fetch_rtt_ms,
                 "host_ingest_spans_per_sec": (
                     round(ingest_rate, 1) if ingest_rate else None
                 ),
@@ -744,14 +681,6 @@ def main():
                     "adoption_bitexact"
                 ),
                 "sketch_impl_matrix": matrix,
-                "lag_note": (
-                    "gross p99 is submit-to-harvest through the real "
-                    "pipeline; each lag sample is PAIRED with a 1-scalar "
-                    "fetch probe concurrent with that harvest's report "
-                    "fetch — p99_net is the p99 of elementwise lag minus "
-                    "the paired fetch time; rtt_p50/p99 give the fetch "
-                    "floor the gross number sits on"
-                ),
             }
         )
     )
@@ -797,38 +726,6 @@ def measure_mitigation_subprocess(timeout_s: float = 1500.0) -> dict:
     keep/drop measurement — hence the wider timeout."""
     return _measure_module_subprocess(
         "opentelemetry_demo_tpu.runtime.mitigbench", timeout_s
-    )
-
-
-def measure_fetch_rtt() -> float:
-    """Median ms of a 1-scalar device→host fetch (the harvest's floor).
-
-    The fetch is exactly what the pipeline's harvest pays per report. Each sample
-    fetches a FRESH device value (jax.Array caches its host copy after
-    the first conversion, so re-fetching the same array times a dict
-    lookup, not the wire).
-    """
-    base = jnp.zeros((), jnp.int32)
-    bump = jax.jit(lambda s, i: s + i)
-    samples = []
-    for i in range(7):
-        fresh = bump(base, i)
-        t0 = time.perf_counter()
-        _ = int(np.asarray(fresh))
-        samples.append((time.perf_counter() - t0) * 1000.0)
-    samples.sort()
-    return round(samples[len(samples) // 2], 3)
-
-
-def measure_lag(rng):
-    """p99 submit→harvest lag via the shared methodology
-    (runtime.lagbench — also the scripts/bench_lag.py engine)."""
-    del rng  # lagbench owns its seeding
-    from opentelemetry_demo_tpu.runtime.lagbench import measure_lag as run
-
-    return run(
-        rate=float(os.environ.get("BENCH_LAG_RATE", 2_000.0)),
-        seconds=float(os.environ.get("BENCH_LAG_SECONDS", 12.0)),
     )
 
 

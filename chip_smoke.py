@@ -54,7 +54,7 @@ CPU_ENV = dict(CHIP_ENV, JAX_PLATFORMS="cpu")
 
 B = 2048  # ANOMALY_BATCH, the compose deployment's batch
 STEP_BATCHES = 6
-RATE = 2_000.0  # spans/s — the Locust-profile rate (runtime/lagbench.py)
+RATE = 2_000.0  # spans/s — the Locust-profile rate
 POST_EVERY_S = 0.05
 ORDERS_PER_S = 2.0
 CLEAN_AFTER_WARM_S = 25.0  # >= two 10 s windows rotate after warm-up

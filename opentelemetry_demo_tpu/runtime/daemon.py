@@ -29,8 +29,6 @@ pass):
                              collector's primary ingress; -1 disables)
 - ``ANOMALY_METRICS_PORT``   Prometheus listen port (default 9464)
 - ``ANOMALY_BATCH``          device batch size (default 2048)
-- ``ANOMALY_HARVEST_INTERVAL``  report readback cadence seconds (default 0
-  = every batch); ``ANOMALY_HARVEST_ASYNC=1`` fetches on a side thread
 - ``ANOMALY_ADAPTIVE_BATCH``  adaptive dispatch-width controller
   (default 1 = on): widens batches in pow2 steps when report readback
   can't keep pace, bounding the skip rate under load spikes; set 0 for
@@ -67,9 +65,9 @@ pass):
   engine: ``runtime.spine`` — the staging ring between batch assembly
   and the donated device step): ``ANOMALY_SPINE_RING`` (pre-allocated
   host staging buffers, default 2 = double buffering; 0 = spine off,
-  pack+put inline on the pump thread), ``ANOMALY_SPINE_OVERLAP``
-  (1 = overlap batch k+1's host→device put with batch k's in-flight
-  step; anomaly_spine_put_overlap_ratio reports the hit rate),
+  pack+put inline on the pump thread; with a step in flight only
+  batches whose put completed dispatch, and
+  anomaly_spine_put_overlap_ratio reports that hit rate),
   ``ANOMALY_SPINE_CHUNK_ROWS`` (pack copy block rows, 0 = whole batch)
 - Hot-standby replication knobs (one registry:
   ``utils.config.REPLICATION_KNOBS``; engine: ``runtime.replication``):
@@ -370,8 +368,8 @@ class DetectorDaemon:
             submit = None
             if endpoint:
                 # The ONE network leg of self-tracing: the shared
-                # background poster (encode on the harvester, POST on
-                # the sender thread — never the pump).
+                # background poster (encode at harvest, POST on the
+                # sender thread — never the pump's device path).
                 self._selftrace_poster = selftrace.make_exporter(endpoint)
                 submit = self._selftrace_poster.submit
             self.selftrace = selftrace.SelfTracer(
@@ -1048,15 +1046,10 @@ class DetectorDaemon:
             flags=flags,
             on_report=self._on_report,
             batch_size=self.batch_size,
-            # Where readback dominates, set an interval (and/or async)
-            # so dispatch never waits on fetch.
-            harvest_interval_s=float(dk["ANOMALY_HARVEST_INTERVAL"]),
-            harvest_async=bool(int(dk["ANOMALY_HARVEST_ASYNC"])),
             # Adaptive width (on by default): bounds the report skip
-            # rate when readback RTT outpaces the batch interval — the
-            # 10× stress regime. The ladder precompiles in the
-            # background below so an escalation never compiles
-            # mid-incident.
+            # rate when harvest can't keep pace with dispatch. The
+            # ladder precompiles in the background below so an
+            # escalation never compiles mid-incident.
             adaptive_batching=bool(int(dk["ANOMALY_ADAPTIVE_BATCH"])),
             # Bounded admission + brownout (the overload half of the
             # fault matrix; knob registry: utils.config.OVERLOAD_KNOBS).
@@ -1073,7 +1066,6 @@ class DetectorDaemon:
             hh_candidates=self._query_candidates,
             # Device-put spine (SPINE_KNOBS; runtime.spine).
             spine_ring=sp["ANOMALY_SPINE_RING"],
-            spine_overlap=bool(int(sp["ANOMALY_SPINE_OVERLAP"])),
             spine_chunk_rows=sp["ANOMALY_SPINE_CHUNK_ROWS"],
             # Self-telemetry (SELFTRACE_KNOBS; runtime.selftrace): the
             # promoted phase histograms + sampled batch-lifecycle traces.
@@ -1087,7 +1079,7 @@ class DetectorDaemon:
             ),
             tenant_quota_rows_s=self._tenant_quota_rows_s,
             # Verdict provenance (PROVENANCE_KNOBS; runtime.provenance):
-            # evidence bundles assembled at flag time on the harvester.
+            # evidence bundles assembled at flag time, at harvest.
             provenance=self.provenance,
             explain_ring=self._provenance_ring,
             # Key lifecycle ladder (KEYSPACE_KNOBS; runtime.keyspace):
@@ -2305,12 +2297,6 @@ class DetectorDaemon:
                 # wedged server — only the wire can.
                 probe=self._probe_grpc,
                 probe_interval_s=10.0,
-            )
-        if self.pipeline.harvest_async:
-            self._supervisor.register(
-                "harvester",
-                restart=self.pipeline.restart_harvester,
-                probe=self.pipeline.harvester_alive,
             )
 
     # -- replication wiring --------------------------------------------
@@ -3932,7 +3918,7 @@ class DetectorDaemon:
             # queue into the pipeline, then stop the workers — BEFORE
             # the pipeline drains, so nothing in flight is lost.
             self.ingest_pool.close()
-        self.pipeline.close()  # drain + stop the harvester thread if any
+        self.pipeline.close()  # drain + stop the spine's stager
         # Final provenance drain AFTER the pipeline drain (bundles the
         # last batches flagged) and BEFORE the writer closes, so they
         # make the sealed log.

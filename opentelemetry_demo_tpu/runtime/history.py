@@ -521,7 +521,7 @@ class HistoryWriter:
         self.spans_sampled_out = 0
         # Evidence bundles (runtime.provenance) awaiting persistence:
         # same bounded drop-oldest handoff as the span queue — the
-        # harvester enqueues a JSON-able dict, the compaction thread
+        # harvest enqueues a JSON-able dict, the compaction thread
         # encodes it as a META-ONLY frame (no columns) so ranged
         # explain reads stay header-only. Flags are rare; the span
         # queue's cap is plenty.
@@ -674,7 +674,7 @@ class HistoryWriter:
     # -- evidence-bundle capture (runtime.provenance) --------------------
 
     def capture_explain(self, bundle: dict) -> None:
-        """Remember one evidence bundle (harvester thread; bounded,
+        """Remember one evidence bundle (pump thread at harvest; bounded,
         never blocks). The bundle is already a plain JSON-able dict —
         no copy-out needed, it is never mutated after build."""
         with self._span_lock:

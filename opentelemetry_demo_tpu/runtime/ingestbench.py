@@ -27,7 +27,17 @@ import numpy as np
 
 from . import native, wire
 from .otlp import MONITORED_ATTR_KEYS, decode_export_request
-from .tensorize import SpanTensorizer
+from .tensorize import SpanColumns, SpanTensorizer
+
+
+def make_columns(rng, rows: int) -> SpanColumns:
+    return SpanColumns(
+        svc=rng.integers(0, 20, size=rows).astype(np.int32),
+        lat_us=rng.gamma(4.0, 250.0, size=rows).astype(np.float32),
+        is_error=(rng.random(rows) < 0.02).astype(np.float32),
+        trace_key=rng.integers(0, 2**63, size=rows, dtype=np.uint64),
+        attr_crc=rng.zipf(1.5, size=rows).astype(np.uint64),
+    )
 
 
 def make_payloads(n_requests: int = 64, spans_per_request: int = 128,

@@ -1,6 +1,6 @@
 """Overload saturation driver: one methodology, bench + tests.
 
-The lagbench/ingestbench sibling for the overload fault class: drive
+The ingestbench sibling for the overload fault class: drive
 the REAL DetectorPipeline at a multiple of its drain capacity, then cut
 the pressure, and measure the graceful-degradation contract end to end:
 
@@ -24,7 +24,7 @@ import time
 import numpy as np
 
 from ..models import AnomalyDetector, DetectorConfig
-from .lagbench import make_columns
+from .ingestbench import make_columns
 from .pipeline import DetectorPipeline
 from .tensorize import SpanColumns
 

@@ -111,19 +111,12 @@ class PipelineStats:
     # report, which covers them (device state folded them in order).
     spans_harvested: int = 0
     flag_events: int = 0
-    # Reports dropped unfetched under a harvest interval (their batches
+    # Reports dropped unfetched past the in-flight bound (their batches
     # still updated device state; only the host-side readback skipped).
     reports_skipped: int = 0
-    # Reports whose host-side processing raised (async harvester only;
-    # the sync path propagates to the caller).
-    harvest_errors: int = 0
     # Bounded window: the exported p99 tracks *current* lag, and memory
     # stays constant in a sidecar that pumps for days.
     lag_ms: deque = field(default_factory=lambda: deque(maxlen=2048))
-    # Paired per-harvest RTT probes (rtt_probe=True): sample i here ran
-    # CONCURRENTLY with lag sample i's report fetch — so lag−rtt is an
-    # elementwise pairing, not a subtraction of two unrelated medians.
-    rtt_ms: deque = field(default_factory=lambda: deque(maxlen=2048))
     # Overload accounting (bounded admission): rows dropped by the
     # overflow shed, per lane. The "error" key exists so the
     # zero-error-lane-loss invariant is an asserted number, not an
@@ -158,21 +151,6 @@ class PipelineStats:
             return 0.0
         return float(np.percentile(np.asarray(self.lag_ms), 99))
 
-    def lag_net_samples(self) -> np.ndarray:
-        """Elementwise lag−RTT over the paired tail (empty w/o probes).
-
-        The net lag is the lag with each harvest's own device→host
-        fetch time taken out: the probe measures THAT harvest's round
-        trip, not a run-level median.
-        """
-        n = min(len(self.lag_ms), len(self.rtt_ms))
-        if n == 0:
-            return np.empty(0, np.float64)
-        lag = np.asarray(self.lag_ms, dtype=np.float64)[-n:]
-        rtt = np.asarray(self.rtt_ms, dtype=np.float64)[-n:]
-        net = lag - rtt
-        return net[~np.isnan(net)]
-
 
 class DetectorPipeline:
     """Drives an :class:`AnomalyDetector` from a span-record source."""
@@ -184,9 +162,6 @@ class DetectorPipeline:
         on_report: Callable[[float, DetectorReport, list[str]], None] | None = None,
         batch_size: int = 2048,
         max_wait_s: float = 0.05,
-        harvest_interval_s: float = 0.0,
-        harvest_async: bool = False,
-        rtt_probe: bool = False,
         adaptive_batching: bool = False,
         max_batch_growth: int = 8,
         queue_max_rows: int = 0,
@@ -198,7 +173,6 @@ class DetectorPipeline:
         exemplar_ring: int = 8,
         hh_candidates: int = 64,
         spine_ring: int = 0,
-        spine_overlap: bool = True,
         spine_chunk_rows: int = 0,
         phase_observe: Callable[[str, float], None] | None = None,
         selftrace=None,
@@ -249,48 +223,17 @@ class DetectorPipeline:
             self._spine = DevicePutSpine(
                 self.tensorizer,
                 depth=spine_ring,
-                overlap=spine_overlap,
                 chunk_rows=spine_chunk_rows,
                 phase_observe=phase_observe,
             )
         self.max_wait_s = max_wait_s
-        # Device→host readback cadence. 0 = harvest a report every pump
-        # (max report fidelity). Where readback is the bottleneck, a
-        # positive interval keeps dispatch free-running and fetches only
-        # the newest report each interval; skipped reports are counted,
-        # and nothing is lost on device — CUSUM/z state evolves every
-        # batch regardless.
-        self.harvest_interval_s = harvest_interval_s
-        self._last_harvest = time.monotonic()
-        # Optional background harvester: on topologies where readback
-        # blocks for a full RTT, fetching on the pump thread steals a
-        # fetch-worth of wall time from dispatch. The harvester thread
-        # takes the newest in-flight report (skipping stale ones) and
-        # does the blocking device_get off the dispatch path.
-        self.harvest_async = harvest_async
-        self._harvest_wake = threading.Event()
-        self._harvest_idle = threading.Event()
-        self._harvest_idle.set()
-        self._harvest_stop = False
-        self._harvest_flush = False  # drain() bypasses the cadence
-        self._harvest_thread: threading.Thread | None = None
-        if harvest_async:
-            self._harvest_thread = threading.Thread(
-                target=self._harvest_loop, name="report-harvester", daemon=True
-            )
-            self._harvest_thread.start()
-        # Paired RTT probing: after each report fetch completes (lag
-        # window closed), time one fresh 1-scalar device→host fetch.
-        # The probe runs beside the harvest's fetch, so lag[i]−rtt[i]
-        # takes that fetch's round trip out of the lag sample.
-        # Off by default — it costs one extra round trip per harvest.
-        self.rtt_probe = rtt_probe
-        self._rtt_state = None
-        self._rtt_bump = None
+        # Reports are harvested on the pump thread, every pump. Set
+        # while drain() runs: the adaptive hold lets go and the spine
+        # waits for every staged put, so the backlog dispatches.
+        self._harvest_flush = False
         # Adaptive batch growth (VERDICT r4 weak #1): when harvest can't
-        # keep pace with dispatch (readback RTT > batch interval — the
-        # 10× stress regime), reports get dropped
-        # unfetched EXACTLY when the operator most wants them. The
+        # keep pace with dispatch, reports past the in-flight bound get
+        # dropped unfetched EXACTLY when the operator most wants them. The
         # controller widens the dispatch batch (powers of two up to
         # ``max_batch_growth``×) until dispatch rate ≤ harvest rate, so
         # every span still reaches device state AND ~every report is
@@ -387,8 +330,8 @@ class DetectorPipeline:
         # answer "how often?" but never enumerate its keys). Everything
         # here is JSON-able (query_meta) so it rides the replication
         # meta block and a read replica answers the same queries from
-        # the same data. Guarded by its own lock: writers are the pump
-        # thread (candidates) and the harvester (exemplars), readers
+        # the same data. Guarded by its own lock: the writer is the pump
+        # thread (candidates at dispatch, exemplars at harvest), readers
         # the replication/query snapshot threads.
         # Per-tenant sketch-namespace quota (the fleet tier's
         # noisy-tenant isolation; knob registry: utils.config
@@ -820,7 +763,7 @@ class DetectorPipeline:
             # Widened regime: hold sub-width dispatches briefly so the
             # batch fills — max_wait_s bounds the added latency, and a
             # quiet stream still flushes on the next pump past it.
-            self._maybe_sync_harvest(keep=0)
+            self._harvest_one(keep=0)
             return
         with self._pending_lock:
             budget = width
@@ -854,12 +797,12 @@ class DetectorPipeline:
                 pass
             else:
                 # Nothing to dispatch — but an idle pump must still
-                # fetch due in-flight reports (outside the pending
-                # lock: the fetch blocks for an RTT and submitters
-                # must not): a report that only ever harvests on the
-                # NEXT batch's pump carries one extra batch interval
-                # of detection lag.
-                self._maybe_sync_harvest(keep=0)
+                # fetch in-flight reports (outside the pending lock:
+                # the fetch blocks for an RTT and submitters must
+                # not): a report that only ever harvests on the NEXT
+                # batch's pump carries one extra batch interval of
+                # detection lag.
+                self._harvest_one(keep=0)
                 return
         else:
             cols = SpanColumns.concat(parts)
@@ -891,17 +834,14 @@ class DetectorPipeline:
                     batch, t_now, t_oldest, t_take, cols, batch.num_valid,
                     trace=trace,
                 )
-        if self.harvest_async:
-            self._harvest_wake.set()
-        else:
-            # Adaptive overlap: with more batches queued, leave the
-            # newest dispatch in flight (device compute overlaps the
-            # fetch — the throughput regime); with the queue drained,
-            # fetch everything now (the low-rate regime, where a kept
-            # report would wait a whole batch interval).
-            with self._pending_lock:
-                keep = 1 if self._pending else 0
-            self._maybe_sync_harvest(keep=keep)
+        # Adaptive overlap: with more batches queued, leave the newest
+        # dispatch in flight (device compute overlaps the fetch — the
+        # throughput regime); with the queue drained, fetch everything
+        # now (the low-rate regime, where a kept report would wait a
+        # whole batch interval).
+        with self._pending_lock:
+            keep = 1 if self._pending else 0
+        self._harvest_one(keep=keep)
 
     def _dispatch_batch(
         self, batch, t_now, t_oldest, t_take, cols, n_valid: int, trace=None
@@ -942,11 +882,11 @@ class DetectorPipeline:
             # to hold_s before dispatch, and that wait IS detection lag.
             # The dispatch's end stamp starts the report wait, and the
             # cumulative dispatched rows credit the harvested counter.
-            # The host-side columns ride along so the harvester can
+            # The host-side columns ride along so the harvest can
             # capture exemplar trace ids AT FLAG TIME from the exact
             # batch that flagged (bounded: ≤3 batches in flight). The
             # batch's sampled self-trace (or None) rides too — the
-            # harvester finishes it after the flag decision.
+            # harvest finishes it after the flag decision.
             self._inflight.append((
                 t_now, t_oldest, report, cols, trace, dispatch.t1,
                 self.stats.spans,
@@ -999,20 +939,15 @@ class DetectorPipeline:
         pump takes only a batch whose put has COMPLETED (a not-ready
         batch dispatches on a later pump — its transfer keeps riding
         behind the running step, which is the whole point); with the
-        device idle, under drain, at the ring bound, or with overlap
-        disabled it waits. So a batch staged with the device idle
-        dispatches in the pump that staged it: it waits for its pack
-        and put, never a whole pump interval."""
+        device idle, under drain, or at the ring bound it waits. So a
+        batch staged with the device idle dispatches in the pump that
+        staged it: it waits for its pack and put, never a whole pump
+        interval."""
         if not self._spine.pending():
             return False  # an empty ring asks the device nothing
         if idle is None:
             idle = self._device_idle()
-        must_wait = (
-            force_wait
-            or not self._spine.overlap
-            or self._harvest_flush
-            or idle
-        )
+        must_wait = force_wait or self._harvest_flush or idle
         staged = self._spine.take(wait=must_wait, idle=idle)
         if staged is None:
             return False
@@ -1029,90 +964,32 @@ class DetectorPipeline:
         )
         return True
 
-    def _maybe_sync_harvest(self, keep: int) -> None:
-        """One due-cadence synchronous harvest (no-op in async mode)."""
-        if self.harvest_async:
-            return
-        if time.monotonic() - self._last_harvest >= self.harvest_interval_s:
-            if self._harvest_one(keep=keep):
-                self._last_harvest = time.monotonic()
-
     def drain(self) -> None:
         """Harvest all in-flight reports (end of stream / shutdown)."""
-        # Raise the flush flag BEFORE pumping the backlog: the async
-        # harvester must not cadence-skip reports dispatched during the
-        # drain itself.
+        # Raise the flush flag BEFORE pumping the backlog: the adaptive
+        # hold must not park rows, and the spine must wait for every
+        # staged put.
         self._harvest_flush = True
         try:
             while self._pending or (
                 self._spine is not None and self._spine.pending()
             ):
                 self.pump()
-            if self.harvest_async:
-                self._drain_async()
-            else:
-                while self._harvest_one(keep=0):
-                    pass
+            while self._harvest_one(keep=0):
+                pass
         finally:
             self._harvest_flush = False
 
-    def _drain_async(self) -> None:
-        while True:
-            with self._inflight_lock:
-                empty = not self._inflight
-            if empty and self._harvest_idle.is_set():
-                break
-            if (
-                self._harvest_thread is None
-                or not self._harvest_thread.is_alive()
-            ):
-                # Dead harvester (should be impossible — the loop
-                # swallows processing errors — but never spin against
-                # it): fall back to synchronous harvest.
-                while self._harvest_one(keep=0):
-                    pass
-                break
-            self._harvest_wake.set()
-            time.sleep(0.005)
-
     def close(self) -> None:
-        """Stop the background harvester (if any) after a final drain."""
+        """Drain, then stop the spine's stager (if any)."""
         self.drain()
         if self._spine is not None:
             self._spine.close()
-        if self._harvest_thread is not None:
-            self._harvest_stop = True
-            self._harvest_wake.set()
-            self._harvest_thread.join(timeout=5.0)
-            self._harvest_thread = None
 
     def spine_stats(self) -> dict | None:
         """The spine's put/overlap counters (None when the spine is
         off) — the daemon's anomaly_spine_* export reads this."""
         return None if self._spine is None else self._spine.stats()
-
-    # -- supervision hooks --------------------------------------------
-
-    def harvester_alive(self) -> bool:
-        """True while the async harvester runs (or isn't configured).
-        The supervisor's probe: a dead harvester means in-flight
-        reports pile up to the skip cap and nothing reaches
-        ``on_report`` — silent from the outside."""
-        if not self.harvest_async:
-            return True
-        return self._harvest_thread is not None and self._harvest_thread.is_alive()
-
-    def restart_harvester(self) -> None:
-        """Respawn a dead async harvester (the supervisor's restart).
-        Safe to call when it's healthy (no-op) or after close()."""
-        if not self.harvest_async or self.harvester_alive():
-            return
-        self._harvest_stop = False
-        self._harvest_idle.set()
-        self._harvest_thread = threading.Thread(
-            target=self._harvest_loop, name="report-harvester", daemon=True
-        )
-        self._harvest_thread.start()
 
     # -- adaptive width controller ------------------------------------
 
@@ -1202,9 +1079,8 @@ class DetectorPipeline:
         times while reports keep dropping. Decay: two consecutive
         all-clean 8-outcome windows halve the width, returning report
         granularity once the pressure clears. Counters are
-        lock-guarded — outcomes arrive from the pump thread AND the
-        harvester (lock order: _inflight_lock → _adapt_lock, never the
-        reverse)."""
+        lock-guarded (lock order: _inflight_lock → _adapt_lock, never
+        the reverse)."""
         if not self.adaptive_batching:
             return
         with self._adapt_lock:
@@ -1263,97 +1139,6 @@ class DetectorPipeline:
 
     # -- report handling ----------------------------------------------
 
-    def _harvest_loop(self) -> None:
-        """Background harvester: blocking readback off the pump thread.
-
-        On the cadence path, takes the NEWEST in-flight report (older
-        ones are superseded — device state already includes them; CUSUM
-        keeps persistent anomalies sticky across skipped readbacks).
-        Under drain() (``_harvest_flush``), processes every remaining
-        report oldest-first — end-of-stream must not lose finals."""
-        while True:
-            self._harvest_wake.wait(timeout=0.05)
-            self._harvest_wake.clear()
-            # The interval knob composes with async mode: between due
-            # times the harvester idles (stale reports keep being
-            # dropped at append time), so the link isn't saturated with
-            # back-to-back readbacks the interval was set to avoid.
-            # drain() bypasses the cadence via _harvest_flush; close()
-            # via _harvest_stop.
-            if (
-                not self._harvest_stop
-                and not self._harvest_flush
-                and time.monotonic() - self._last_harvest < self.harvest_interval_s
-            ):
-                continue
-            with self._inflight_lock:
-                if not self._inflight:
-                    if self._harvest_stop:
-                        return
-                    continue
-                # Cadence path: an older report whose device→host copy
-                # (started at dispatch, copy_to_host_async) has already
-                # COMPLETED costs ~nothing to fetch — process it instead
-                # of skipping. Only a genuinely-behind report (copy
-                # still in flight; fetching it would block the fresher
-                # one for an RTT) is dropped as superseded — device
-                # state already includes it. The drain path must NOT
-                # skip: end-of-stream harvests every remaining report
-                # oldest-first, matching sync-mode drain semantics.
-                if not self._harvest_flush:
-                    while len(self._inflight) > 1:
-                        is_ready = getattr(
-                            self._inflight[0][2], "is_ready", None
-                        )
-                        try:
-                            if is_ready is not None and is_ready():
-                                break  # oldest is free to fetch
-                        except Exception:  # noqa: BLE001 — treat as not ready
-                            pass
-                        self._inflight.popleft()
-                        self.stats.reports_skipped += 1
-                        self._note_outcome(skipped=True)
-                item = self._inflight.popleft()
-                self._harvest_idle.clear()
-            self._last_harvest = time.monotonic()
-            try:
-                self._process_report(item)
-            except Exception:  # noqa: BLE001 — a raising on_report must
-                # not kill the harvester: the thread is the only
-                # consumer of _inflight, and drain()/close() would spin
-                # forever against a dead one.
-                self.stats.harvest_errors += 1
-            finally:
-                self._harvest_idle.set()
-
-    def _start_rtt_probe(self) -> dict:
-        """Launch a 1-scalar device→host fetch CONCURRENT with the
-        report fetch it pairs with.
-
-        Concurrency is the point: both round trips run at the same
-        moment, so jitter hits both and cancels in lag−rtt. Each probe
-        bumps a device counter so the fetched array is fresh —
-        jax.Array caches its host copy, so re-fetching the same array
-        would time a dict lookup, not the wire.
-        """
-        import jax.numpy as jnp
-
-        if self._rtt_bump is None:
-            self._rtt_bump = jax.jit(lambda s: s + 1)
-            self._rtt_state = jnp.zeros((), jnp.int32)
-        self._rtt_state = self._rtt_bump(self._rtt_state)
-        arr = self._rtt_state
-        res: dict = {}
-
-        def run():
-            t0 = time.perf_counter()
-            _ = int(np.asarray(arr))
-            res["rtt"] = (time.perf_counter() - t0) * 1e3
-
-        th = threading.Thread(target=run, name="rtt-probe", daemon=True)
-        th.start()
-        return {"thread": th, "res": res}
-
     def _harvest_one(self, keep: int = 1) -> bool:
         """Synchronous harvest of the oldest in-flight report beyond
         ``keep`` (keep=1 leaves one dispatch in flight for overlap)."""
@@ -1407,7 +1192,7 @@ class DetectorPipeline:
     def _provenance_snapshot(self) -> dict | None:
         """Flag-time device→host fetch of the baseline/sketch state the
         evidence bundles cite (EWMA means/vars, CUSUM accumulators, the
-        live CMS/HLL banks). Harvester thread, under ``_dispatch_lock``
+        live CMS/HLL banks). Pump thread, under ``_dispatch_lock``
         — the donation-race contract: ``detector.state`` may be donated
         away mid-read otherwise. Flags are rare and the fetch is the
         same order of work as one replication snapshot; a failed fetch
@@ -1438,7 +1223,7 @@ class DetectorPipeline:
         prov_state: dict | None = None, trace_id: str | None = None,
     ) -> list[str]:
         """At flag time: link each flagged service to concrete trace
-        ids from the batch that flagged it (harvester thread).
+        ids from the batch that flagged it (pump thread, at harvest).
 
         The exemplar is the first 8 bytes of the OTLP trace id (the
         tensorizer's ``trace_key``, little-endian) rendered as hex —
@@ -1638,7 +1423,6 @@ class DetectorPipeline:
     def _process_report(self, item) -> None:
         t_batch, t_oldest, dev_report, cols, trace, t_sent, rows = item
         self._note_outcome(skipped=False)
-        probe = self._start_rtt_probe() if self.rtt_probe else None
         # Single-array fetch + host-side unpack (see pump()).
         with selftrace.span(
             SPAN_HARVEST, observe=self.phase_observe, trace=trace
@@ -1663,9 +1447,6 @@ class DetectorPipeline:
             # already host numpy, so the K-window evidence history
             # costs an append, never a device round trip.
             self._provenance.observe_report(float(t_batch), report)
-        if probe is not None:
-            probe["thread"].join(timeout=10.0)
-            self.stats.rtt_ms.append(probe["res"].get("rtt", float("nan")))
         threshold = float(
             self.flags.evaluate(FLAG_THRESHOLD, self.detector.config.z_threshold)
         )

@@ -105,7 +105,7 @@ class ProvenanceEngine:
 
     Owned by the daemon, fed by the pipeline: ``observe_report`` on
     every harvested report (any thread; internally locked), ``build``
-    per flagged service (harvester thread, under the pipeline's query
+    per flagged service (pump thread at harvest, under the pipeline's query
     lock — cheap numpy only). The bundle RING lives in the pipeline
     beside the anomaly ring so it rides ``query_meta`` replication;
     this engine is stateless apart from the trajectory deque and the

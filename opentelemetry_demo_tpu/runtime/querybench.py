@@ -30,7 +30,7 @@ import time
 import numpy as np
 
 from ..models import AnomalyDetector, DetectorConfig
-from .lagbench import make_columns
+from .ingestbench import make_columns
 from .pipeline import DetectorPipeline
 from .provenance import REASON_LATENCY, ProvenanceEngine
 from .query import QueryEngine, QueryService

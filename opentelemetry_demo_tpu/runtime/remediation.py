@@ -636,8 +636,8 @@ class TokenBucket:
 class RemediationController:
     """The supervised control loop (module docstring for the contract).
 
-    ``observe(t, flagged)`` is the hot-path entry (harvester/pump
-    thread): per-service streak bookkeeping under one lock, never I/O.
+    ``observe(t, flagged)`` is the hot-path entry (pump
+    thread, at harvest): per-service streak bookkeeping under one lock, never I/O.
     ``tick(t)`` (pump cadence) advances deadlines/budget when no
     reports arrive. Actuator writes run on the worker thread with
     fencing, bounded timeouts and capped jittered retry.

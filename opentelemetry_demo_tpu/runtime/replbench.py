@@ -1,6 +1,6 @@
 """Failover + fleet-reshard drill driver: one methodology, bench + tests.
 
-The overloadbench/lagbench sibling for the replication fault class:
+The overloadbench sibling for the replication fault class:
 run a REAL detector as primary with a live replication link, a standby
 applying deltas, then kill the primary abruptly (RST, the SIGKILL
 shape from the standby's point of view) and measure the hot-standby
@@ -47,7 +47,7 @@ import time
 import numpy as np
 
 from ..models import AnomalyDetector, DetectorConfig
-from .lagbench import make_columns
+from .ingestbench import make_columns
 from .pipeline import DetectorPipeline
 from .replication import EpochFence, ReplicationPrimary, ReplicationStandby
 

@@ -202,7 +202,7 @@ def sampled(seq: int, rate: float) -> bool:
 
 class BatchTrace:
     """One sampled batch's lifecycle: spans accumulated across the
-    pump and harvester threads (handed off through the pipeline's
+    pump and stager threads (handed off through the pipeline's
     in-flight deque — never concurrently mutated), exported once at
     finish."""
 
@@ -479,7 +479,7 @@ class SelfTracer:
 
     Thread contract: ``flush_segment`` is called by decode-pool
     workers (bounded deque, GIL-atomic appends); ``begin``/``finish``
-    run on the pump/harvester threads, and a BatchTrace is only ever
+    run on the pump thread, and a BatchTrace is only ever
     touched by the thread currently holding the batch (the pipeline's
     in-flight hand-off orders the accesses).
     """
@@ -527,7 +527,7 @@ class SelfTracer:
         return trace
 
     def finish(self, trace: BatchTrace) -> bytes:
-        """Encode + hand off one completed trace (harvester/pump
+        """Encode + hand off one completed trace (pump
         thread). Returns the encoded request (tests read it back)."""
         body = encode_selftrace_request(trace, self.service)
         self.traces_exported += 1

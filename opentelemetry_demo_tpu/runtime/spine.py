@@ -21,8 +21,7 @@ working through a small ring of pre-allocated host staging buffers:
   is still unharvested. With a step running it takes only a batch
   whose put already completed (``overlap_hits``; the rest dispatch on a
   later pump, their transfer riding behind the step), and it waits
-  only at the ring bound, under ``drain()`` or with overlap off
-  (``overlap_misses``). With the device idle it waits for every staged
+  only at the ring bound or under ``drain()`` (``overlap_misses``). With the device idle it waits for every staged
   batch, the one it just staged included, and dispatches them in the
   same pass (``idle_waits``): a batch waits behind a running step,
   never behind an idle device, and the low-rate regime pays the pack
@@ -41,8 +40,7 @@ the host column views (whose lifetime the ingest pool's scratch
 tickets already manage). tests/test_spine.py hammers dispatch-vs-put
 concurrency under donation to pin that.
 
-Knobs ride ``utils.config.SPINE_KNOBS`` (ring depth / overlap /
-chunk rows), threaded daemon → compose → k8s like every family.
+Knobs ride ``utils.config.SPINE_KNOBS`` (ring depth / chunk rows), threaded daemon → compose → k8s like every family.
 """
 
 from __future__ import annotations
@@ -98,7 +96,6 @@ class DevicePutSpine:
         self,
         tensorizer: SpanTensorizer,
         depth: int = 2,
-        overlap: bool = True,
         chunk_rows: int = 0,
         device_put=None,
         phase_observe=None,
@@ -107,7 +104,6 @@ class DevicePutSpine:
             raise ValueError(f"spine ring depth must be >= 1 (got {depth})")
         self.tensorizer = tensorizer
         self.depth = int(depth)
-        self.overlap = bool(overlap)
         self.chunk_rows = int(chunk_rows)
         self._device_put = device_put
         # The stage and put-wait spans observe their phases here (the

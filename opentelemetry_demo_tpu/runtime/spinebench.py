@@ -27,7 +27,7 @@ import time
 
 from ..models.detector import AnomalyDetector, DetectorConfig
 from .ingest_pool import IngestPool, IngestPoolSaturated
-from .ingestbench import make_payloads
+from .ingestbench import make_columns, make_payloads
 from .pipeline import DetectorPipeline
 
 
@@ -41,8 +41,6 @@ def measure_kernel_ref(
     apples and oranges). Slope-of-two-regions with a terminating
     fetch, the repo's honest-timing rule (bench.py module doc)."""
     import numpy as np
-
-    from .lagbench import make_columns
 
     det = AnomalyDetector(config)
     rng = np.random.default_rng(0)
@@ -79,7 +77,6 @@ def measure_e2e(
     ring: int = 2,
     seconds: float = 4.0,
     batch: int = 2048,
-    overlap: bool = True,
     num_services: int = 16,
     hll_p: int = 8,
     cms_width: int = 1024,
@@ -172,7 +169,6 @@ def measure_e2e(
         ),
         batch_size=batch,
         spine_ring=ring,
-        spine_overlap=overlap,
         phase_observe=phase_observe,
         selftrace=tracer,
         provenance=prov,
@@ -310,7 +306,7 @@ def measure_explain_overhead(
     OFF/ON rounds over one payload set so CPU drift hits both arms,
     the real ``ProvenanceEngine`` on the ON arm. ``ratio`` =
     off_rate / on_rate; bench.py gates it at ≤ 1.03 — the evidence
-    plane must ride the harvester for free. None without the native
+    plane must ride the harvest for free. None without the native
     decoder."""
     payloads = kw.pop("payloads", None) or make_payloads(
         kw.get("n_requests", 32), kw.get("spans_per_request", 256)

@@ -4,8 +4,8 @@ The reference drives every *shop-side* failure through flagd fault
 flags, and the detector measures time-to-detect for all of them — but a
 detector whose own ingest thread dies on a broker restart is blind in
 exactly the incident it exists for. This module is the supervision tree
-for the daemon's components (Kafka orders pump, OTLP receivers, report
-harvester, checkpoint writer): each is registered with a restart hook
+for the daemon's components (Kafka orders pump, OTLP receivers,
+checkpoint writer): each is registered with a restart hook
 and/or a liveness probe, crashes trigger bounded exponential backoff
 with jitter, and a restart budget detects crash loops.
 

@@ -213,7 +213,7 @@ def provisioned_dashboards() -> list[Dashboard]:
                       "traces/s"),
                 # Verdict provenance plane (runtime.provenance): how
                 # many flags got an evidence bundle, what assembling
-                # one costs on the harvester, how many shipped to the
+                # one costs at harvest, how many shipped to the
                 # history tier / OTLP logs, and which build is
                 # running (restart forensics beside bundle times).
                 Panel("Anomaly explanations built",

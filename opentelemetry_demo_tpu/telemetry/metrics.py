@@ -393,7 +393,7 @@ ANOMALY_FLEET_ADOPTION_TTA = "anomaly_fleet_adoption_seconds"
 # Verdict provenance plane (runtime.provenance): evidence bundles
 # built at flag time (per flagged service), bundles exported as OTLP
 # log records through the background poster, and what a flag-time
-# build costs on the harvester thread — plus the fleet build-identity
+# build costs at harvest — plus the fleet build-identity
 # gauge (one 1-valued series per process, labeled with the package
 # version, the wire frame version and the jax build) a rolling resize
 # checks for mixed-build shards.

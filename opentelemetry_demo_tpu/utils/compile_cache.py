@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placeable from outside.
 
 Every entry point that compiles for the chip (the daemon, ``bench.py``,
-``scripts/bench_lag.py``, ``chip_smoke.py``) calls
+``chip_smoke.py``) calls
 :func:`enable_compile_cache` before its first compile. Tests do not:
 their compiles are for the CPU or for a described chip.
 """

@@ -331,16 +331,6 @@ DAEMON_KNOBS: dict[str, tuple[str, object, str]] = {
         "longest serve-loop sleep in seconds when no rows arrive "
         "(admitted rows wake the loop at once)",
     ),
-    "ANOMALY_HARVEST_INTERVAL": (
-        "float", 0.0,
-        "report readback cadence seconds (0 = harvest every batch; "
-        "set where readback RTT dominates the batch interval)",
-    ),
-    "ANOMALY_HARVEST_ASYNC": (
-        "int", 0,
-        "1 = fetch reports on a background harvester thread so "
-        "dispatch never waits on a device->host round trip",
-    ),
     "ANOMALY_ADAPTIVE_BATCH": (
         "int", 1,
         "adaptive dispatch-width controller (1 = on): widens batches "
@@ -416,13 +406,6 @@ SPINE_KNOBS: dict[str, tuple[str, object, str]] = {
         "so batch k+1's transfer rides behind batch k's in-flight "
         "donated step (2 = classic double buffering; 0 = spine off — "
         "pack+put inline on the pump thread, the pre-spine path)",
-    ),
-    "ANOMALY_SPINE_OVERLAP": (
-        "int", 1,
-        "1 = with a step in flight, dispatch only batches whose put "
-        "already completed (transfer hidden behind compute; "
-        "anomaly_spine_put_overlap_ratio tracks the hit rate); 0 = "
-        "always wait for the put synchronously (A/B debugging)",
     ),
     "ANOMALY_SPINE_CHUNK_ROWS": (
         "int", 0,
@@ -900,7 +883,7 @@ PROVENANCE_KNOBS: dict[str, tuple[str, object, str]] = {
     "ANOMALY_PROVENANCE_ENABLE": (
         "int", 1,
         "1 = build an evidence bundle per flagged service at flag "
-        "time (harvester thread, beside exemplar capture) and serve "
+        "time (at harvest, beside exemplar capture) and serve "
         "it on /query/explain; 0 = provenance off (flags and "
         "exemplars still capture — bundles are explanation, not "
         "detection)",
@@ -1100,9 +1083,6 @@ BENCH_KNOBS: dict[str, tuple[str, object, str]] = {
     "BENCH_REPL": ("int", 1, "0 skips the replication/failover drill"),
     "BENCH_QUERY": ("int", 1, "0 skips the query-plane bench"),
     "BENCH_QUALITY": ("int", 1, "0 skips detection-quality scenarios"),
-    "BENCH_LAG_STRESS": ("int", 1, "0 skips the lag stress leg"),
-    "BENCH_LAG_RATE": ("float", 2000.0, "lag bench offered spans/s"),
-    "BENCH_LAG_SECONDS": ("float", 12.0, "lag bench duration"),
     "BENCH_SPINE": ("int", 1, "0 skips the e2e ingest-spine bench"),
     "BENCH_SELFTRACE": (
         "int", 1,

@@ -7,6 +7,7 @@ layer an 8-device virtual mesh. What must be proven for the chip is
 compiled for a described v5e in ``tests/test_chip_compile.py``.
 """
 
+import contextlib
 import os
 
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -27,6 +28,24 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@contextlib.contextmanager
+def _hold_harvest(pipe):
+    """Hold a pipeline's report harvest: ``pump`` dispatches but
+    fetches nothing, so reports pile up against the in-flight bound
+    (the harvest-can't-keep-pace regime). Restores the harvest on
+    exit — leave the block before ``drain()``/``close()``."""
+    pipe._harvest_one = lambda keep=1: False
+    try:
+        yield pipe
+    finally:
+        del pipe._harvest_one
+
+
+@pytest.fixture
+def hold_harvest():
+    return _hold_harvest
 
 
 def missing_env_resource(resource: str) -> str | None:

@@ -26,8 +26,6 @@ corrupt checkpoint at boot  cold start + ``anomaly_checkpoint_
                             corrupt_total``; bad file moved aside
 mid-frame truncation / RST  (FaultWire) consumer drops + reconnects;
                             daemon survives, resumes on clear
-dead harvester thread       supervisor restarts it;
-                            ``anomaly_component_restarts_total``
 crash-looping component     DEGRADED state, ``anomaly_degraded`` 1,
                             per-component gRPC health NOT_SERVING
 ==========================  =========================================
@@ -609,34 +607,6 @@ class TestOrdersChaos:
 
 
 class TestSupervisedDaemon:
-    def test_dead_harvester_restarted(self, monkeypatch, tmp_path):
-        _daemon_env(monkeypatch, tmp_path, ANOMALY_HARVEST_ASYNC="1")
-        daemon = DetectorDaemon(DetectorConfig(**SMALL))
-        daemon.start()
-        try:
-            assert daemon.pipeline.harvester_alive()
-            # Murder the harvester thread (stands in for an unhandled
-            # exception escaping it).
-            daemon.pipeline._harvest_stop = True
-            daemon.pipeline._harvest_wake.set()
-            daemon.pipeline._harvest_thread.join(timeout=5.0)
-            assert not daemon.pipeline.harvester_alive()
-            deadline = time.monotonic() + 10.0
-            t = 0.0
-            while time.monotonic() < deadline:
-                daemon.step(t)
-                t += 0.25
-                if daemon.pipeline.harvester_alive():
-                    break
-                time.sleep(0.05)
-            assert daemon.pipeline.harvester_alive(), "harvester not revived"
-            assert daemon._supervisor.restarts("harvester") >= 1
-            assert (
-                'anomaly_component_restarts_total{component="harvester"}'
-            ) in _scrape(daemon)
-        finally:
-            daemon.shutdown()
-
     def test_component_health_on_grpc_surface(self, monkeypatch, tmp_path):
         """Per-component health rides the existing grpc.health.v1
         ingress: anomaly.component.<name> answers SERVING while UP,

@@ -44,7 +44,7 @@ import pytest
 from opentelemetry_demo_tpu.models import AnomalyDetector, DetectorConfig
 from opentelemetry_demo_tpu.ops import cms, hll
 from opentelemetry_demo_tpu.runtime.daemon import DetectorDaemon
-from opentelemetry_demo_tpu.runtime.lagbench import make_columns
+from opentelemetry_demo_tpu.runtime.ingestbench import make_columns
 from opentelemetry_demo_tpu.runtime.pipeline import DetectorPipeline
 from opentelemetry_demo_tpu.runtime.query import (
     QueryEngine,

@@ -168,9 +168,9 @@ def _counter(daemon, name) -> float:
 
 class TestHarvestedCounter:
     def test_harvested_counter_covers_skipped_and_in_flight_rows(
-        self, monkeypatch, tmp_path
+        self, monkeypatch, tmp_path, hold_harvest
     ):
-        """Three batches dispatched with no harvest due: the oldest
+        """Three batches dispatched with the harvest held: the oldest
         report is skipped, two stay in flight. The harvested counter
         credits exactly the rows each harvested report covers (the
         skipped batch's with the next one); the processed counter keeps
@@ -182,12 +182,12 @@ class TestHarvestedCounter:
         daemon = DetectorDaemon(DetectorConfig(**SMALL))
         try:
             pipe = daemon.pipeline
-            pipe.harvest_interval_s = 3600.0  # no harvest falls due
             rng = np.random.default_rng(9)
             rows = [40, 50, 60]
-            for i, n in enumerate(rows):
-                pipe.submit_columns(make_columns(rng, n))
-                pipe.pump(float(i))
+            with hold_harvest(pipe):
+                for i, n in enumerate(rows):
+                    pipe.submit_columns(make_columns(rng, n))
+                    pipe.pump(float(i))
             assert pipe.stats.reports_skipped == 1
             assert _counter(daemon, tm.ANOMALY_SPANS_HARVESTED) == 0.0
             assert pipe._harvest_one(keep=1)

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from opentelemetry_demo_tpu.models import AnomalyDetector, DetectorConfig
-from opentelemetry_demo_tpu.runtime.lagbench import make_columns
+from opentelemetry_demo_tpu.runtime.ingestbench import make_columns
 from opentelemetry_demo_tpu.runtime.pipeline import DetectorPipeline
 from opentelemetry_demo_tpu.runtime.spine import DevicePutSpine
 from opentelemetry_demo_tpu.runtime.tensorize import SpanTensorizer
@@ -274,13 +274,15 @@ class TestIdleDevice:
         pipe = DetectorPipeline(
             det, on_report=on_report, batch_size=128, spine_ring=2
         )
-        # No harvest falls due: the reports stay in flight, so the pump
-        # has to tell a finished step from a running one.
-        pipe.harvest_interval_s = 3600.0
         pipe._spine._device_put = put
         return pipe
 
-    def test_idle_device_dispatches_in_the_pump_that_staged(self):
+    # The tests hold the harvest: the reports stay in flight, so the
+    # pump has to tell a finished step from a running one.
+
+    def test_idle_device_dispatches_in_the_pump_that_staged(
+        self, hold_harvest
+    ):
         def slow_put(a):
             time.sleep(0.005)  # the put outlasts the pump's own pass
             return jax.device_put(a)
@@ -289,24 +291,27 @@ class TestIdleDevice:
         pipe = self._pipe(det, slow_put)
         rng = np.random.default_rng(8)
         try:
-            for k in (1, 2, 3):
-                pipe.submit_columns(make_columns(rng, 100))
-                pipe.pump(0.05 * k)
-                # Dispatched within the call that staged it.
-                assert pipe.stats.batches == k
-                assert pipe._spine.pending() == 0
-                st = pipe.spine_stats()
-                assert st["idle_waits"] == k
-                assert st["overlap_hits"] == st["overlap_misses"] == 0
-                # The previous step finishes before the next pump, its
-                # report still unharvested in flight.
-                jax.block_until_ready(pipe._inflight[-1][2])
-            assert len(pipe._inflight) == 2  # the in-flight bound
+            with hold_harvest(pipe):
+                for k in (1, 2, 3):
+                    pipe.submit_columns(make_columns(rng, 100))
+                    pipe.pump(0.05 * k)
+                    # Dispatched within the call that staged it.
+                    assert pipe.stats.batches == k
+                    assert pipe._spine.pending() == 0
+                    st = pipe.spine_stats()
+                    assert st["idle_waits"] == k
+                    assert st["overlap_hits"] == st["overlap_misses"] == 0
+                    # The previous step finishes before the next pump,
+                    # its report still unharvested in flight.
+                    jax.block_until_ready(pipe._inflight[-1][2])
+                assert len(pipe._inflight) == 2  # the in-flight bound
         finally:
             pipe.close()
         assert pipe.stats.spans == 300
 
-    def test_running_step_never_blocks_the_pump_on_a_put(self):
+    def test_running_step_never_blocks_the_pump_on_a_put(
+        self, hold_harvest
+    ):
         gate = threading.Event()
         gate.set()
 
@@ -323,25 +328,29 @@ class TestIdleDevice:
         )
         rng = np.random.default_rng(9)
         try:
-            pipe.submit_columns(make_columns(rng, 100))
-            pipe.pump(0.05)  # nothing in flight: an idle wait
-            assert pipe.stats.batches == 1
-            gate.clear()  # the next put wedges while the step runs
-            pipe.submit_columns(make_columns(rng, 100))
-            t0 = time.monotonic()
-            pipe.pump(0.10)
-            assert time.monotonic() - t0 < 5.0  # did not wait on the put
-            assert pipe.stats.batches == 1
-            assert pipe._spine.pending() == 1
-            gate.set()
-            staged = pipe._spine._staged[0]
-            assert staged.ready.wait(10.0)
-            pipe.pump(0.15)  # the step still runs; the put is done
-            assert pipe.stats.batches == 2
-            assert pipe._spine.pending() == 0
-            st = pipe.spine_stats()
-            assert st["overlap_hits"] == 1 and st["overlap_misses"] == 0
-            assert st["idle_waits"] == 1 and st["overlap_ratio"] == 1.0
+            with hold_harvest(pipe):
+                pipe.submit_columns(make_columns(rng, 100))
+                pipe.pump(0.05)  # nothing in flight: an idle wait
+                assert pipe.stats.batches == 1
+                gate.clear()  # the next put wedges while the step runs
+                pipe.submit_columns(make_columns(rng, 100))
+                t0 = time.monotonic()
+                pipe.pump(0.10)
+                # Did not wait on the put.
+                assert time.monotonic() - t0 < 5.0
+                assert pipe.stats.batches == 1
+                assert pipe._spine.pending() == 1
+                gate.set()
+                staged = pipe._spine._staged[0]
+                assert staged.ready.wait(10.0)
+                pipe.pump(0.15)  # the step still runs; the put is done
+                assert pipe.stats.batches == 2
+                assert pipe._spine.pending() == 0
+                st = pipe.spine_stats()
+                assert st["overlap_hits"] == 1
+                assert st["overlap_misses"] == 0
+                assert st["idle_waits"] == 1
+                assert st["overlap_ratio"] == 1.0
         finally:
             gate.set()
             finished.set()
@@ -350,7 +359,9 @@ class TestIdleDevice:
         assert times == [0.05, 0.10]
         assert pipe.stats.spans == 200
 
-    def test_a_batch_left_staged_goes_with_the_next_pump(self):
+    def test_a_batch_left_staged_goes_with_the_next_pump(
+        self, hold_harvest
+    ):
         """A pump that meets a running step leaves its batch staged.
         The next pump, which stages one of its own, finds the device
         idle and dispatches both, oldest first: no batch waits a pump
@@ -368,23 +379,24 @@ class TestIdleDevice:
         pipe = self._pipe(det, gated_put)
         rng = np.random.default_rng(11)
         try:
-            pipe.submit_columns(make_columns(rng, 100))
-            pipe.pump(0.05)  # nothing in flight: an idle wait
-            gate.clear()  # step 0 runs and the next put is slow
-            pipe.submit_columns(make_columns(rng, 100))
-            pipe.pump(0.10)
-            assert pipe.stats.batches == 1
-            assert pipe._spine.pending() == 1
-            gate.set()
-            finished.set()
-            for k in (3, 4, 5):
-                # The previous step finishes before the next pump.
-                jax.block_until_ready(pipe._inflight[-1][2]._arr)
+            with hold_harvest(pipe):
                 pipe.submit_columns(make_columns(rng, 100))
-                pipe.pump(0.05 * k)
-                assert pipe.stats.batches == k
-                assert pipe._spine.pending() == 0
-            assert pipe.spine_stats()["idle_waits"] == 5
+                pipe.pump(0.05)  # nothing in flight: an idle wait
+                gate.clear()  # step 0 runs and the next put is slow
+                pipe.submit_columns(make_columns(rng, 100))
+                pipe.pump(0.10)
+                assert pipe.stats.batches == 1
+                assert pipe._spine.pending() == 1
+                gate.set()
+                finished.set()
+                for k in (3, 4, 5):
+                    # The previous step finishes before the next pump.
+                    jax.block_until_ready(pipe._inflight[-1][2]._arr)
+                    pipe.submit_columns(make_columns(rng, 100))
+                    pipe.pump(0.05 * k)
+                    assert pipe.stats.batches == k
+                    assert pipe._spine.pending() == 0
+                assert pipe.spine_stats()["idle_waits"] == 5
         finally:
             gate.set()
             finished.set()
@@ -465,3 +477,69 @@ class TestLifecycle:
         pipe = DetectorPipeline(det, batch_size=128)  # spine off
         assert pipe.spine_stats() is None
         pipe.close()
+
+
+class TestStaleReadbackEnv:
+    """The benchmark's config files still set the readback switches
+    the daemon no longer has; it ignores them."""
+
+    # The dropped variables, by the name after the ``ANOMALY_`` prefix:
+    # the registry holds none of them, and this test is their one
+    # mention outside the records.
+    STALE = [("HARVEST_ASYNC", "1"), ("HARVEST_INTERVAL", "3600"),
+             ("SPINE_OVERLAP", "0")]
+
+    @pytest.mark.parametrize(
+        "name,value", STALE, ids=[f"ANOMALY_{n}={v}" for n, v in STALE]
+    )
+    def test_stale_readback_env_is_ignored(
+        self, monkeypatch, tmp_path, name, value
+    ):
+        from opentelemetry_demo_tpu.runtime.daemon import DetectorDaemon
+
+        from .test_selftrace import _daemon_env
+
+        _daemon_env(
+            monkeypatch, tmp_path, ANOMALY_SPINE_RING="2",
+            **{f"ANOMALY_{name}": value},
+        )
+        gate = threading.Event()
+        gate.set()
+        daemon = DetectorDaemon(DetectorConfig(**SMALL))
+        try:
+            pipe = daemon.pipeline
+            rng = np.random.default_rng(12)
+            for i in range(4):
+                pipe.submit_columns(make_columns(rng, 50))
+                pipe.pump(float(i))
+                # Harvested on the pump thread, in the pump that
+                # dispatched it.
+                assert not pipe._inflight
+                assert pipe.stats.spans_harvested == 50 * (i + 1)
+            assert pipe.stats.reports_skipped == 0
+            names = {t.name for t in threading.enumerate()}
+            assert not names & {"report-harvester", "rtt-probe"}
+            # With a step running, a batch whose put is not done stays
+            # staged: the pump does not wait on it.
+            pipe._device_idle = lambda: False
+
+            def gated_put(a):
+                gate.wait(10.0)
+                return jax.device_put(a)
+
+            pipe._spine._device_put = gated_put
+            gate.clear()
+            pipe.submit_columns(make_columns(rng, 50))
+            t0 = time.monotonic()
+            pipe.pump(4.0)
+            assert time.monotonic() - t0 < 5.0
+            assert pipe._spine.pending() == 1
+            gate.set()
+            assert pipe._spine._staged[0].ready.wait(10.0)
+            pipe.pump(5.0)
+            assert pipe._spine.pending() == 0
+            assert pipe.spine_stats()["overlap_hits"] == 1
+            assert pipe.stats.spans_harvested == 250
+        finally:
+            gate.set()
+            daemon.shutdown()
