@@ -235,6 +235,92 @@ def detector_step_packed(config: "DetectorConfig", state: DetectorState, *args):
     return new_state, report_pack(report)
 
 
+# One key's flag-time evidence (the provenance bundle's state block),
+# as (name, shape) in wire order: the key's EWMA/CUSUM rows, the
+# current-slot span totals and step, the key's current HLL registers
+# and the current CMS counts at its candidates' columns. Float leaves
+# ride the int32 wire by bitcast (exact), so the fetch is one transfer.
+_EVIDENCE_F32 = frozenset({
+    "lat_mean", "lat_var", "err_mean", "rate_mean", "rate_var",
+    "card_mean", "cusum", "span_total",
+})
+
+
+def _evidence_layout(config: "DetectorConfig", k: int):
+    t, nw = config.num_taus, config.num_windows
+    return (
+        ("lat_mean", (t,)), ("lat_var", (t,)), ("err_mean", (t,)),
+        ("rate_mean", (t,)), ("rate_var", (t,)), ("card_mean", (nw,)),
+        ("cusum", (3,)), ("span_total", (nw,)), ("step_idx", ()),
+        ("hll_regs", (nw, 1 << config.hll_p)),
+        ("cms_cells", (nw, config.cms_depth, k)),
+    )
+
+
+def evidence_bytes(config: "DetectorConfig", k: int) -> int:
+    """Bytes one key's :func:`evidence_gather` moves to the host."""
+    return 4 * sum(
+        int(np.prod(shape)) for _n, shape in _evidence_layout(config, k)
+    )
+
+
+def evidence_gather(
+    config: "DetectorConfig",
+    state: DetectorState,
+    key: jnp.ndarray,  # int32[] — the flagged key's row
+    cols: jnp.ndarray,  # int32[D, K] — CMS columns of its candidates
+) -> jnp.ndarray:
+    """One key's flag-time evidence as ONE int32 vector; jit WITHOUT
+    donation (it reads the live state between steps).
+
+    ``cols`` is padded to the pipeline's candidate cap, so there is one
+    compiled shape per config. :func:`evidence_unpack` restores the
+    fields host-side."""
+    depth = cols.shape[0]
+    leaves = {
+        "lat_mean": state.lat_mean[key],
+        "lat_var": state.lat_var[key],
+        "err_mean": state.err_mean[key],
+        "rate_mean": state.rate_mean[key],
+        "rate_var": state.rate_var[key],
+        "card_mean": state.card_mean[key],
+        "cusum": state.cusum[key],
+        "span_total": state.span_total[:, 0],
+        "step_idx": state.step_idx,
+        "hll_regs": state.hll_bank[:, 0, key],
+        "cms_cells": state.cms_bank[:, 0][
+            :, jnp.arange(depth)[:, None], cols
+        ],
+    }
+    return jnp.concatenate([
+        (
+            jax.lax.bitcast_convert_type(leaves[name], jnp.int32)
+            if name in _EVIDENCE_F32
+            else leaves[name]
+        ).reshape(-1)
+        for name, _shape in _evidence_layout(config, cols.shape[1])
+    ])
+
+
+def evidence_unpack(flat, config: "DetectorConfig", k: int) -> dict:
+    """Host-side inverse of :func:`evidence_gather` (numpy fields)."""
+    flat = np.asarray(flat)
+    out: dict = {}
+    pos = 0
+    for name, shape in _evidence_layout(config, k):
+        n = int(np.prod(shape))
+        leaf = flat[pos:pos + n]
+        if name in _EVIDENCE_F32:
+            leaf = leaf.view(np.float32)
+        out[name] = leaf.reshape(shape)
+        pos += n
+    if pos != flat.size:
+        raise ValueError(
+            f"evidence length {flat.size} != expected {pos}"
+        )
+    return out
+
+
 def detector_init(config: DetectorConfig) -> DetectorState:
     nw, s, t = config.num_windows, config.num_services, config.num_taus
     return DetectorState(
@@ -598,6 +684,19 @@ class AnomalyDetector:
         )
         self._step_packed = jax.jit(
             partial(detector_step_packed, self.config), donate_argnums=0
+        )
+        self._evidence = jax.jit(partial(evidence_gather, self.config))
+
+    def gather_evidence(
+        self, key: int, cols: np.ndarray, state: DetectorState | None = None
+    ) -> jnp.ndarray:
+        """Dispatch one key's :func:`evidence_gather` on ``state`` (the
+        live state by default); returns the device vector unfetched.
+        The live state is donated by the next step, so a caller on
+        another thread dispatches under the pipeline's dispatch lock."""
+        return self._evidence(
+            self.state if state is None else state,
+            np.int32(key), np.asarray(cols, np.int32),
         )
 
     def _args(self, batch: TensorBatch, t_now: float):

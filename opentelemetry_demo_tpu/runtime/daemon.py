@@ -1009,7 +1009,11 @@ class DetectorDaemon:
         self._explain_poster = None
         self._provenance_ring = int(pv["ANOMALY_PROVENANCE_RING"])
         self._explanations_seen = 0
+        self._fetch_bytes_seen = 0
         if int(pv["ANOMALY_PROVENANCE_ENABLE"]):
+            self.registry.counter_add(
+                tele_metrics.ANOMALY_PROVENANCE_FETCH_BYTES, 0.0
+            )
             self.provenance = provenance.ProvenanceEngine(
                 self.detector.config,
                 topk=int(pv["ANOMALY_PROVENANCE_TOPK"]),
@@ -1130,6 +1134,7 @@ class DetectorDaemon:
                 "Adaptive-width ladder precompiles at boot, by width "
                 "and outcome (ok|error)",
             )
+        if self.pipeline.adaptive_batching or self.provenance is not None:
             threading.Thread(
                 target=self._warm_ladder,
                 name="width-ladder-warmup", daemon=True,
@@ -2033,10 +2038,19 @@ class DetectorDaemon:
     # -- report → metrics ---------------------------------------------
 
     def _warm_ladder(self) -> None:
-        """Background ladder precompile. A width that fails is logged
-        with its error and counted (outcome="error"); the daemon stays
-        up and the controller would pay that compile again at
-        escalation time."""
+        """Background ladder precompile, the flag-time evidence gather
+        first (so it is compiled once the last width is counted). A
+        width that fails is logged with its error and counted
+        (outcome="error"); the daemon stays up and the controller would
+        pay that compile again at escalation time, as the first flag
+        would for a gather that failed."""
+        if self.provenance is not None:
+            try:
+                self.pipeline.warm_evidence()
+            except Exception:  # noqa: BLE001 — logged; boot survives it
+                logging.getLogger(__name__).exception(
+                    "evidence gather failed to compile"
+                )
         for width in self.pipeline.ladder_widths():
             try:
                 self.pipeline.warm_width(width)
@@ -2545,6 +2559,13 @@ class DetectorDaemon:
                 tele_metrics.ANOMALY_EXPLANATIONS_BUILT, float(delta)
             )
             self._explanations_seen = built
+        fetched = self.pipeline.provenance_fetch_bytes
+        if fetched > self._fetch_bytes_seen:
+            self.registry.counter_add(
+                tele_metrics.ANOMALY_PROVENANCE_FETCH_BYTES,
+                float(fetched - self._fetch_bytes_seen),
+            )
+            self._fetch_bytes_seen = fetched
         for seconds in self.provenance.take_build_samples():
             self.registry.histogram_observe(
                 tele_metrics.ANOMALY_EXPLAIN_LATENCY, seconds,

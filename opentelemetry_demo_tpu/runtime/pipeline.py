@@ -45,7 +45,12 @@ from typing import Callable, Iterable
 import jax
 import numpy as np
 
-from ..models.detector import AnomalyDetector, DetectorReport, report_unpack
+from ..models.detector import (
+    AnomalyDetector,
+    DetectorReport,
+    evidence_unpack,
+    report_unpack,
+)
 from ..ops.hashing import splitmix64_np
 from ..utils.flags import FlagEvaluator
 from . import selftrace
@@ -55,6 +60,7 @@ from .provenance import (
     REASON_ERROR_RATE,
     REASON_LATENCY,
     REASON_THROUGHPUT,
+    candidate_columns,
 )
 from .selftrace import (
     PHASE_HARVEST_LAG,
@@ -62,6 +68,7 @@ from .selftrace import (
     PHASE_REPORT_WAIT,
     PHASE_RING_WAIT,
     SPAN_DISPATCH,
+    SPAN_EVIDENCE,
     SPAN_EXPORT,
     SPAN_FLAG,
     SPAN_HARVEST,
@@ -390,6 +397,10 @@ class DetectorPipeline:
         self._provenance = provenance
         self._explain_ring: deque = deque(maxlen=max(int(explain_ring), 1))
         self.explanations_built = 0
+        # Bytes the flag-time evidence gathers moved to the host
+        # (anomaly_provenance_fetch_bytes_total; delta discipline as
+        # explanations_built).
+        self.provenance_fetch_bytes = 0
         # Bundles awaiting OTLP log export, drained by the daemon's
         # export tick (bounded, drop-oldest — freshness over
         # completeness, the exporter queue's own discipline).
@@ -1069,6 +1080,29 @@ class DetectorPipeline:
         )
         jax.device_get(report)  # force the compile + execute
 
+    def warm_evidence(self) -> None:
+        """Compile and run the flag-time evidence gather (see
+        :meth:`_provenance_evidence`) on a state copy, so the first
+        flag compiles nothing; raises what the compiler raises."""
+        import jax.numpy as jnp
+
+        with self._dispatch_lock:
+            state_copy = jax.tree_util.tree_map(
+                jnp.copy, self.detector.state
+            )
+        cols = np.zeros(
+            (self.detector.config.cms_depth, self._evidence_k), np.int32
+        )
+        jax.device_get(
+            self.detector.gather_evidence(0, cols, state=state_copy)
+        )
+
+    @property
+    def _evidence_k(self) -> int:
+        """Candidate columns per gather: the candidate cap, so every
+        gather has one compiled shape."""
+        return max(self._hh_cand_max, 1)
+
     def _note_outcome(self, skipped: bool) -> None:
         """Feed the width controller one report outcome.
 
@@ -1189,38 +1223,65 @@ class DetectorPipeline:
                     )
                 ring.extend(tail)
 
-    def _provenance_snapshot(self) -> dict | None:
-        """Flag-time device→host fetch of the baseline/sketch state the
-        evidence bundles cite (EWMA means/vars, CUSUM accumulators, the
-        live CMS/HLL banks). Pump thread, under ``_dispatch_lock``
-        — the donation-race contract: ``detector.state`` may be donated
-        away mid-read otherwise. Flags are rare and the fetch is the
-        same order of work as one replication snapshot; a failed fetch
-        costs the bundle its state block, never the report path."""
+    def _flag_candidates(self, keys: list[int]) -> dict[int, list[int]]:
+        """Each flagged key's top-k candidates, most recent first: the
+        ring the top-k query reads, fixed here so the evidence gather
+        and the bundle see the same list."""
+        with self._query_lock:
+            return {
+                i: list(dict.fromkeys(
+                    reversed(self._hh_cands.get(i) or ())
+                ))[: self._hh_cand_max]
+                for i in keys
+            }
+
+    def _provenance_evidence(
+        self, cands: dict[int, list[int]], trace=None
+    ) -> dict[int, dict] | None:
+        """Flag-time evidence for each flagged key: its EWMA/CUSUM
+        rows, current HLL registers and the current CMS counts at its
+        candidates' columns, gathered on the device
+        (``AnomalyDetector.gather_evidence``). Pump thread. The gathers
+        are dispatched under ``_dispatch_lock`` — the donation-race
+        contract; no step runs between them, so every key reads the
+        same state — and fetched after it is released: they are fresh
+        buffers no step donates. A failed fetch costs the bundles
+        their state block, never the report path."""
+        cfg = self.detector.config
+        k = self._evidence_k
         try:
-            with self._dispatch_lock:
-                state = self.detector.state
-                return jax.device_get({
-                    "lat_mean": state.lat_mean,
-                    "lat_var": state.lat_var,
-                    "err_mean": state.err_mean,
-                    "rate_mean": state.rate_mean,
-                    "rate_var": state.rate_var,
-                    "card_mean": state.card_mean,
-                    "card_var": state.card_var,
-                    "cusum": state.cusum,
-                    "cms_bank": state.cms_bank,
-                    "hll_bank": state.hll_bank,
-                    "span_total": state.span_total,
-                    "step_idx": state.step_idx,
-                })
+            cols = {
+                i: np.zeros((cfg.cms_depth, k), np.int32) for i in cands
+            }
+            for i, crcs in cands.items():
+                if crcs:
+                    cols[i][:, : len(crcs)] = candidate_columns(
+                        i, crcs, cfg.cms_depth, cfg.cms_width
+                    )
+            with selftrace.span(
+                SPAN_EVIDENCE, observe=self.phase_observe, trace=trace
+            ):
+                with self._dispatch_lock:
+                    flats = {
+                        i: self.detector.gather_evidence(i, c)
+                        for i, c in cols.items()
+                    }
+                    for flat in flats.values():
+                        flat.copy_to_host_async()
+                host = {i: np.asarray(flat) for i, flat in flats.items()}
+            self.provenance_fetch_bytes += sum(
+                h.nbytes for h in host.values()
+            )
+            return {i: evidence_unpack(h, cfg, k) for i, h in host.items()}
         except Exception:  # noqa: BLE001 — evidence is advisory; the
             # report (and the anomaly event) must land regardless.
             return None
 
     def _capture_exemplars(
         self, t_batch, cols, report, flags_np, threshold,
-        prov_state: dict | None = None, trace_id: str | None = None,
+        evidence: dict[int, dict] | None = None,
+        cands: dict[int, list[int]] | None = None,
+        trace_id: str | None = None,
     ) -> list[str]:
         """At flag time: link each flagged service to concrete trace
         ids from the batch that flagged it (pump thread, at harvest).
@@ -1287,22 +1348,17 @@ class DetectorPipeline:
                 captured.extend(traces)
                 bundle_ref = None
                 if self._provenance is not None:
-                    # Evidence bundle per flagged service: candidates
-                    # come from the same ring the top-k query reads
-                    # (already under _query_lock here); seq is the
-                    # detector step from the dispatch-lock snapshot so
-                    # the id is a pure function of replicated
-                    # coordinates.
+                    # Evidence bundle per flagged service, from the
+                    # candidates its evidence was gathered for; seq is
+                    # the detector step of that evidence, so the id is
+                    # a pure function of replicated coordinates.
+                    ev = evidence.get(i) if evidence else None
                     seq = (
-                        int(prov_state["step_idx"])
-                        if prov_state is not None
-                        and "step_idx" in prov_state
+                        int(ev["step_idx"])
+                        if ev is not None
                         else self.stats.flag_events
                     )
                     names = self.tensorizer.service_names
-                    cands = list(dict.fromkeys(
-                        reversed(self._hh_cands.get(i) or ())
-                    ))[: self._hh_cand_max]
                     bundle = self._provenance.build(
                         t_batch=float(t_batch),
                         seq=seq,
@@ -1312,8 +1368,8 @@ class DetectorPipeline:
                         ),
                         signals=signals,
                         exemplars=traces,
-                        state=prov_state,
-                        hh_candidates=cands,
+                        state=ev,
+                        hh_candidates=(cands or {}).get(i, []),
                         trace_id=trace_id,
                     )
                     self._explain_ring.append(bundle)
@@ -1474,21 +1530,21 @@ class DetectorPipeline:
             ) as flag:
                 self.stats.flag_events += 1
                 names = self.tensorizer.service_names
+                keys = [int(i) for i in np.nonzero(flags_np)[0]]
                 flagged = [
                     names[i] if i < len(names) else f"svc-{i}"
-                    for i in np.nonzero(flags_np)[0]
+                    for i in keys
                 ]
-                prov_state = (
-                    self._provenance_snapshot()
-                    if self._provenance is not None
-                    else None
-                )
+                cands = evidence = None
+                if self._provenance is not None:
+                    cands = self._flag_candidates(keys)
+                    evidence = self._provenance_evidence(cands, trace)
                 # The flag span carries span LINKS to the exemplar shop
                 # traces captured from THIS batch — a detector batch
                 # trace in Jaeger jumps straight to the evidence.
                 flag.links = tuple(self._capture_exemplars(
                     t_batch, cols, report, flags_np, threshold,
-                    prov_state=prov_state,
+                    evidence=evidence, cands=cands,
                     trace_id=(
                         trace.trace_id.hex() if trace is not None else None
                     ),

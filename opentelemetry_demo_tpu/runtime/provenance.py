@@ -16,11 +16,15 @@ Design constraints the shape falls out of:
   the per-head columns (one global deque of compact rows, sliced
   per-service at flag time), so the K-window history costs an append,
   never a device_get.
-- **Flag-time state comes from the dispatch-lock snapshot.** The EWMA
-  baselines and CMS/HLL banks live on device; the pipeline fetches
-  them ONCE per flagging batch under ``_dispatch_lock`` (flags are
-  rare — the same discipline as the replication snapshot) and hands
-  the arrays here. This module never touches the detector or a lock.
+- **Flag-time state is gathered per key on the device.** The EWMA
+  baselines and CMS/HLL banks live on device; for each flagged key
+  the pipeline dispatches ``AnomalyDetector.gather_evidence`` under
+  ``_dispatch_lock`` (the key's EWMA/CUSUM rows, its current HLL
+  registers, the current CMS counts at its candidates' columns —
+  about 52 KB in the shop geometry, not the whole banks), fetches it
+  outside the lock and hands the arrays here. A fault flags every
+  report while it lasts, so this is on the verdict path. This module
+  never touches the detector or a lock.
 - **Bundles are plain JSON-able dicts with deterministic ids.** The
   id hashes (epoch, seq, service) through the same scalar splitmix64
   the selftrace ids use, so primary and replica — and a replay of the
@@ -47,7 +51,7 @@ from typing import Callable
 
 import numpy as np
 
-from ..ops.cms import cms_indices_np, cms_query_np
+from ..ops.cms import cms_indices_np
 from ..ops.hashing import split_hi_lo_np, splitmix64_np
 from ..ops.hll import hll_estimate_np
 from .selftrace import splitmix64
@@ -96,8 +100,20 @@ def bundle_id(epoch: int, seq: int, service: int) -> str:
     )
 
 
-def _row(arr, svc: int) -> list[float]:
-    return [float(x) for x in np.asarray(arr)[svc]]
+def _row(arr) -> list[float]:
+    return [float(x) for x in np.asarray(arr)]
+
+
+def candidate_columns(
+    svc: int, cands: list[int], depth: int, width: int
+) -> np.ndarray:
+    """CMS columns ``int32[depth, len(cands)]`` of one key's candidate
+    attributes: the SAME fold ``query.topk_heavy_hitters`` runs (key |
+    svc<<32 → splitmix → rows)."""
+    crc = np.asarray(cands, dtype=np.uint64)
+    key = crc | (np.uint64(svc) << np.uint64(32))
+    hi, lo = split_hi_lo_np(splitmix64_np(key))
+    return cms_indices_np(hi, lo, depth, width)
 
 
 class ProvenanceEngine:
@@ -178,9 +194,11 @@ class ProvenanceEngine:
     ) -> dict:
         """One flagged service → one evidence bundle (JSON-able dict).
 
-        ``state`` is the dispatch-lock snapshot (host numpy arrays) or
-        None when the fetch was skipped/failed — the bundle degrades to
-        trajectory + signals rather than refusing to exist."""
+        ``state`` is the key's flag-time evidence (host numpy, as
+        ``models.detector.evidence_unpack`` returns it, gathered for
+        ``hh_candidates``) or None when the fetch failed — the bundle
+        degrades to trajectory + signals rather than refusing to
+        exist."""
         t0 = time.perf_counter()
         epoch = int(self._epoch_fn()) if self._epoch_fn is not None else 0
         cfg = self.config
@@ -206,30 +224,28 @@ class ProvenanceEngine:
         }
         if state is not None:
             try:
-                self._attach_state(bundle, state, int(service), hh_candidates)
+                self._attach_state(bundle, state, hh_candidates)
             except (KeyError, IndexError, ValueError):
-                # A mismatched snapshot (mid-resize shapes) costs the
+                # Evidence of the wrong shape (mid-resize) costs the
                 # state block, not the bundle.
                 pass
         with self._lock:
             self._build_s.append(time.perf_counter() - t0)
         return bundle
 
-    def _attach_state(
-        self, bundle: dict, state: dict, svc: int, cands: list[int]
-    ) -> None:
+    def _attach_state(self, bundle: dict, ev: dict, cands: list[int]) -> None:
         bundle["ewma"] = {
             "latency": {
-                "mean": _row(state["lat_mean"], svc),
-                "var": _row(state["lat_var"], svc),
+                "mean": _row(ev["lat_mean"]),
+                "var": _row(ev["lat_var"]),
             },
-            "error_rate": {"mean": _row(state["err_mean"], svc)},
+            "error_rate": {"mean": _row(ev["err_mean"])},
             "throughput": {
-                "mean": _row(state["rate_mean"], svc),
-                "var": _row(state["rate_var"], svc),
+                "mean": _row(ev["rate_mean"]),
+                "var": _row(ev["rate_var"]),
             },
         }
-        cus = np.asarray(state["cusum"])[svc]
+        cus = np.asarray(ev["cusum"])
         thr = self.config.cusum_thresholds
         bundle["cusum"] = {
             "latency_up": float(cus[0]),
@@ -239,41 +255,33 @@ class ProvenanceEngine:
         }
         # Cardinality head evidence: live estimate per window vs the
         # learned EWMA baseline — the delta is the head's own signal.
-        est = hll_estimate_np(np.asarray(state["hll_bank"])[:, 0])  # [W#, S]
-        base = np.asarray(state["card_mean"])[svc]  # [W#]
+        est = hll_estimate_np(ev["hll_regs"])  # [W#]
+        base = np.asarray(ev["card_mean"])  # [W#]
         nw = min(est.shape[0], base.shape[0])
-        if svc < est.shape[1]:
-            bundle["cardinality"] = {
-                "estimate": [float(est[w, svc]) for w in range(nw)],
-                "baseline_mean": [float(base[w]) for w in range(nw)],
-                "delta": [
-                    float(est[w, svc]) - float(base[w]) for w in range(nw)
-                ],
-            }
-        bundle["top_keys"] = self._topk_contributors(state, svc, cands)
+        bundle["cardinality"] = {
+            "estimate": [float(est[w]) for w in range(nw)],
+            "baseline_mean": [float(base[w]) for w in range(nw)],
+            "delta": [float(est[w]) - float(base[w]) for w in range(nw)],
+        }
+        bundle["top_keys"] = self._topk_contributors(ev, cands)
 
-    def _topk_contributors(
-        self, state: dict, svc: int, cands: list[int]
-    ) -> list[dict]:
-        """Exact CMS point queries for the candidate keys — the SAME
-        fold ``query.topk_heavy_hitters`` runs (key | svc<<32 →
-        splitmix → rows), snapshotted into evidence at flag time so
-        the bundle stays truthful after the window rolls."""
+    def _topk_contributors(self, ev: dict, cands: list[int]) -> list[dict]:
+        """Exact CMS point queries for the candidate keys: the minimum
+        over the D rows of the counts gathered at each candidate's
+        columns (``candidate_columns``), which is what ``cms_query_np``
+        computes over the whole table — snapshotted into evidence at
+        flag time so the bundle stays truthful after the window
+        rolls."""
         if not cands:
             return []
-        cur = np.asarray(state["cms_bank"])[:, 0]  # [W#, D, C]
-        depth, width = cur.shape[-2], cur.shape[-1]
-        span_total = np.asarray(state["span_total"])[:, 0]  # [W#]
+        cells = np.asarray(ev["cms_cells"])  # [W#, D, K ≥ len(cands)]
+        counts = np.min(cells[..., : len(cands)], axis=-2)  # [W#, B]
         crc = np.asarray(cands, dtype=np.uint64)
-        key = crc | (np.uint64(svc) << np.uint64(32))
-        hi, lo = split_hi_lo_np(splitmix64_np(key))
-        idx = cms_indices_np(hi, lo, depth, width)
-        counts = cms_query_np(cur, idx)  # [W#, B]
         sel = counts[-1]  # longest window: the attribution horizon
         order = sorted(
             range(len(cands)), key=lambda i: (-int(sel[i]), int(crc[i]))
         )[: self.topk]
-        denom = max(float(span_total[-1]), 1.0)
+        denom = max(float(np.asarray(ev["span_total"])[-1]), 1.0)
         return [
             {
                 "attr_crc": f"0x{int(crc[i]):08x}",

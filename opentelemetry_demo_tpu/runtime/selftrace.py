@@ -83,6 +83,9 @@ SPAN_PUT = "detector.device_put"
 SPAN_DISPATCH = "detector.dispatch"
 SPAN_HARVEST = "detector.harvest"
 SPAN_FLAG = "detector.flag"
+# Inside the flag span: the flagged keys' evidence gather, from taking
+# the dispatch lock to holding host arrays.
+SPAN_EVIDENCE = "detector.evidence"
 # Spans around the daemon's own layers (the served path outside a
 # batch): one OTLP trace request at a door, the serve loop's sleep and
 # pass, the 1 s self-report scrape, the checkpoint write, and one
@@ -113,6 +116,7 @@ PHASE_DISPATCH = "dispatch"
 PHASE_HARVEST = "harvest"
 PHASE_HARVEST_LAG = "harvest_lag"
 PHASE_FLAG = "flag"
+PHASE_EVIDENCE = "evidence"
 PHASE_DOOR = "door"
 PHASE_LOOP_STEP = "loop_step"
 PHASE_SELF_REPORT = "self_report"
@@ -144,6 +148,7 @@ SPAN_FOR_PHASE = {
     PHASE_DISPATCH: SPAN_DISPATCH,
     PHASE_HARVEST: SPAN_HARVEST,
     PHASE_FLAG: SPAN_FLAG,
+    PHASE_EVIDENCE: SPAN_EVIDENCE,
     PHASE_DOOR: SPAN_DOOR,
     PHASE_LOOP_STEP: SPAN_STEP,
     PHASE_SELF_REPORT: SPAN_SELF_REPORT,

@@ -400,6 +400,9 @@ ANOMALY_FLEET_ADOPTION_TTA = "anomaly_fleet_adoption_seconds"
 ANOMALY_EXPLANATIONS_BUILT = "anomaly_explanations_built_total"
 ANOMALY_EXPLANATIONS_EXPORTED = "anomaly_explanations_exported_total"
 ANOMALY_EXPLAIN_LATENCY = "anomaly_explain_latency_seconds"  # histogram
+# Bytes the flag-time evidence gathers moved device → host (one key's
+# rows and candidate cells per flagged key, not the banks).
+ANOMALY_PROVENANCE_FETCH_BYTES = "anomaly_provenance_fetch_bytes_total"
 # {version=, frame_version=, jax=, platform=, device_kind=}
 ANOMALY_BUILD_INFO = "anomaly_build_info"
 # Adaptive-width ladder precompile at boot, one count per width:

@@ -116,3 +116,17 @@ def test_cms_hist_mxu_compiles(one_chip):
         jax.ShapeDtypeStruct((n,), jnp.bool_),
     )
     assert "tpu_custom_call" in hlo
+
+
+def test_evidence_gather_compiles(one_chip):
+    """The flag-time per-key evidence gather at the compose geometry,
+    its candidate columns padded to the daemon's cap of 64."""
+    config = detector.DetectorConfig()
+    state = jax.eval_shape(lambda: detector.detector_init(config))
+    _compile(
+        partial(detector.evidence_gather, config),
+        one_chip,
+        state,
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((config.cms_depth, 64), jnp.int32),
+    )

@@ -28,15 +28,29 @@ from __future__ import annotations
 
 import json
 import re
+import threading
 import time
 from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
+import jax
+import jax.numpy as jnp
+
 from opentelemetry_demo_tpu.models import DetectorConfig
+from opentelemetry_demo_tpu.models.detector import (
+    AnomalyDetector,
+    detector_init,
+    evidence_bytes,
+)
+from opentelemetry_demo_tpu.ops.cms import cms_indices_np, cms_query_np
+from opentelemetry_demo_tpu.ops.hashing import split_hi_lo_np, splitmix64_np
+from opentelemetry_demo_tpu.ops.hll import hll_estimate_np
 from opentelemetry_demo_tpu.runtime import history
 from opentelemetry_demo_tpu.runtime.daemon import DetectorDaemon
+from opentelemetry_demo_tpu.runtime.pipeline import DetectorPipeline
+from opentelemetry_demo_tpu.telemetry import metrics as tele_metrics
 from opentelemetry_demo_tpu.runtime.provenance import (
     HEAD_CUSUM,
     HEAD_EWMA_Z,
@@ -152,6 +166,138 @@ class TestEngine:
         assert eng.take_build_samples() == []
 
 
+# --- per-key evidence gather -----------------------------------------
+
+CAP = 16
+
+
+def _random_state(config: DetectorConfig, seed: int):
+    """A detector state with every leaf filled from ``seed``: HLL rows
+    from near-empty (linear counting) to dense, CMS counts, EWMA and
+    CUSUM rows, span totals and a step index."""
+    rng = np.random.default_rng(seed)
+    st = detector_init(config)
+    hll = rng.integers(0, 12, st.hll_bank.shape)
+    keep = rng.uniform(size=st.hll_bank.shape[:-1])[..., None]
+    hll = np.where(rng.uniform(size=hll.shape) < keep, hll, 0)
+
+    def f32(leaf):
+        return jnp.asarray(
+            rng.normal(size=leaf.shape).astype(np.float32) * 10.0
+        )
+
+    return st._replace(
+        hll_bank=jnp.asarray(hll.astype(np.int32)),
+        cms_bank=jnp.asarray(
+            rng.integers(0, 5000, st.cms_bank.shape).astype(np.int32)
+        ),
+        span_total=jnp.asarray(
+            rng.uniform(0.0, 1e5, st.span_total.shape).astype(np.float32)
+        ),
+        lat_mean=f32(st.lat_mean), lat_var=f32(st.lat_var),
+        err_mean=f32(st.err_mean), rate_mean=f32(st.rate_mean),
+        rate_var=f32(st.rate_var), card_mean=f32(st.card_mean),
+        card_var=f32(st.card_var), cusum=f32(st.cusum),
+        step_idx=jnp.int32(int(rng.integers(1, 10_000))),
+    )
+
+
+def _reference_state_block(config, full, svc: int, cands, topk: int):
+    """The bundle's state block from a full host copy of the state:
+    ``hll_estimate_np`` over the whole current bank, ``cms_query_np``
+    over the whole current CMS slot."""
+    def row(leaf):
+        return [float(x) for x in np.asarray(leaf)[svc]]
+
+    est = hll_estimate_np(np.asarray(full.hll_bank)[:, 0])  # [W#, S]
+    base = np.asarray(full.card_mean)[svc]
+    nw = config.num_windows
+    cus = np.asarray(full.cusum)[svc]
+    block = {
+        "ewma": {
+            "latency": {"mean": row(full.lat_mean), "var": row(full.lat_var)},
+            "error_rate": {"mean": row(full.err_mean)},
+            "throughput": {
+                "mean": row(full.rate_mean), "var": row(full.rate_var),
+            },
+        },
+        "cusum": {
+            "latency_up": float(cus[0]), "error_up": float(cus[1]),
+            "rate_down": float(cus[2]),
+            "thresholds": [float(x) for x in config.cusum_thresholds],
+        },
+        "cardinality": {
+            "estimate": [float(est[w, svc]) for w in range(nw)],
+            "baseline_mean": [float(base[w]) for w in range(nw)],
+            "delta": [
+                float(est[w, svc]) - float(base[w]) for w in range(nw)
+            ],
+        },
+        "top_keys": [],
+    }
+    if cands:
+        cur = np.asarray(full.cms_bank)[:, 0]  # [W#, D, C]
+        crc = np.asarray(cands, dtype=np.uint64)
+        hi, lo = split_hi_lo_np(
+            splitmix64_np(crc | (np.uint64(svc) << np.uint64(32)))
+        )
+        counts = cms_query_np(
+            cur, cms_indices_np(hi, lo, config.cms_depth, config.cms_width)
+        )
+        sel = counts[-1]
+        order = sorted(
+            range(len(cands)), key=lambda i: (-int(sel[i]), int(crc[i]))
+        )[:topk]
+        denom = max(float(np.asarray(full.span_total)[-1, 0]), 1.0)
+        block["top_keys"] = [
+            {
+                "attr_crc": f"0x{int(crc[i]):08x}",
+                "count": int(sel[i]),
+                "counts": [int(c) for c in counts[:, i]],
+                "share": float(np.float32(int(sel[i]) / denom)),
+            }
+            for i in order
+        ]
+    return block
+
+
+@pytest.mark.parametrize("n_cands", [0, 1, CAP])
+@pytest.mark.parametrize("n_keys", [1, 3])
+def test_gathered_evidence_builds_the_full_copy_bundle(n_keys, n_cands):
+    """Bundles built from the per-key device gather equal, field for
+    field and bit for bit, bundles built from a full host copy of the
+    same state; the fetch moves the per-key size, not the banks."""
+    config = DetectorConfig(**SMALL)
+    det = AnomalyDetector(config)
+    det.state = _random_state(config, seed=100 * n_keys + n_cands)
+    full = jax.device_get(det.state)
+    engine = ProvenanceEngine(config, topk=5)
+    pipe = DetectorPipeline(
+        det, batch_size=128, provenance=engine, hh_candidates=CAP
+    )
+    rng = np.random.default_rng(n_keys + n_cands)
+    keys = [int(k) for k in rng.choice(config.num_services, n_keys, False)]
+    cands = {
+        k: [int(c) for c in rng.choice(2**32, n_cands, replace=False)]
+        for k in keys
+    }
+    evidence = pipe._provenance_evidence(cands)
+    assert evidence is not None and set(evidence) == set(keys)
+    assert pipe.provenance_fetch_bytes == n_keys * evidence_bytes(config, CAP)
+    assert pipe.provenance_fetch_bytes < n_keys * full.hll_bank.nbytes
+    for k in keys:
+        assert int(evidence[k]["step_idx"]) == int(full.step_idx)
+        b = engine.build(
+            t_batch=1.0, seq=int(full.step_idx), service=k, label=f"s{k}",
+            signals=[REASON_LATENCY], exemplars=[], state=evidence[k],
+            hh_candidates=cands[k], trace_id=None,
+        )
+        want = _reference_state_block(config, full, k, cands[k], topk=5)
+        assert {name: b[name] for name in want} == want
+        assert len(b["top_keys"]) == min(n_cands, 5)
+        json.dumps(b)
+
+
 # --- live daemon ------------------------------------------------------
 
 
@@ -254,6 +400,45 @@ class TestLiveExplain:
             assert status == 200 and doc["data"]["bundles"] == []
         finally:
             daemon.shutdown()
+
+
+def test_first_flag_compiles_nothing_and_fetches_per_key_bytes():
+    """With the boot warm-up done, the report that first flags compiles
+    nothing, and the provenance fetch counter rises by one key's
+    evidence per bundle, far below one HLL bank."""
+    with _env():
+        daemon = DetectorDaemon(DetectorConfig(**SMALL))
+    for t in threading.enumerate():
+        if t.name == "width-ladder-warmup":
+            t.join(timeout=60)
+    daemon.start()
+    try:
+        _intern(daemon)
+        rng = np.random.default_rng(11)
+        t = _feed(daemon, rng, steps=35)
+        pipe = daemon.pipeline
+        assert pipe.stats.flag_events == 0
+        for _ in range(40):
+            compiles = daemon.compiles.count
+            built = pipe.explanations_built
+            fetched = pipe.provenance_fetch_bytes
+            t = _feed(daemon, rng, steps=1, t0=t, anomaly_from=0)
+            if pipe.stats.flag_events:
+                break
+        assert pipe.stats.flag_events, "the fault never flagged"
+        assert daemon.compiles.count == compiles
+        bundles = pipe.explanations_built - built
+        per_key = evidence_bytes(daemon.detector.config, pipe._evidence_k)
+        assert bundles >= 1
+        assert pipe.provenance_fetch_bytes - fetched == bundles * per_key
+        assert per_key < daemon.detector.state.hll_bank.nbytes
+        daemon._export_provenance_stats()
+        counters, _ = daemon.registry.snapshot()
+        assert counters[
+            (tele_metrics.ANOMALY_PROVENANCE_FETCH_BYTES, ())
+        ] == float(pipe.provenance_fetch_bytes)
+    finally:
+        daemon.shutdown()
 
 
 # --- restart survival through the retention ladder --------------------
