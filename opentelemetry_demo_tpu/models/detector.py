@@ -27,7 +27,7 @@ sketch deltas merge with ``psum``/``pmax`` (see ``parallel``).
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, update_wrapper
 from typing import NamedTuple
 
 import jax
@@ -254,13 +254,6 @@ def _evidence_layout(config: "DetectorConfig", k: int):
         ("cusum", (3,)), ("span_total", (nw,)), ("step_idx", ()),
         ("hll_regs", (nw, 1 << config.hll_p)),
         ("cms_cells", (nw, config.cms_depth, k)),
-    )
-
-
-def evidence_bytes(config: "DetectorConfig", k: int) -> int:
-    """Bytes one key's :func:`evidence_gather` moves to the host."""
-    return 4 * sum(
-        int(np.prod(shape)) for _n, shape in _evidence_layout(config, k)
     )
 
 
@@ -685,7 +678,12 @@ class AnomalyDetector:
         self._step_packed = jax.jit(
             partial(detector_step_packed, self.config), donate_argnums=0
         )
-        self._evidence = jax.jit(partial(evidence_gather, self.config))
+        # Named, unlike the step's bare partial (jit__unknown on the
+        # device): a trace that reads the step by that name must not
+        # count the gather as a step.
+        self._evidence = jax.jit(update_wrapper(
+            partial(evidence_gather, self.config), evidence_gather
+        ))
 
     def gather_evidence(
         self, key: int, cols: np.ndarray, state: DetectorState | None = None

@@ -523,7 +523,8 @@ class HistoryWriter:
         # same bounded drop-oldest handoff as the span queue — the
         # harvest enqueues a JSON-able dict, the compaction thread
         # encodes it as a META-ONLY frame (no columns) so ranged
-        # explain reads stay header-only. Flags are rare; the span
+        # explain reads stay header-only. At most one bundle per
+        # flagged service per report, far fewer than spans; the span
         # queue's cap is plenty.
         self._explain_queue: deque = deque(
             maxlen=max(int(span_queue_max), 1)

@@ -42,7 +42,6 @@ from opentelemetry_demo_tpu.models import DetectorConfig
 from opentelemetry_demo_tpu.models.detector import (
     AnomalyDetector,
     detector_init,
-    evidence_bytes,
 )
 from opentelemetry_demo_tpu.ops.cms import cms_indices_np, cms_query_np
 from opentelemetry_demo_tpu.ops.hashing import split_hi_lo_np, splitmix64_np
@@ -169,6 +168,19 @@ class TestEngine:
 # --- per-key evidence gather -----------------------------------------
 
 CAP = 16
+
+
+def evidence_bytes(config: DetectorConfig, k: int) -> int:
+    """Bytes one key's evidence gather moves: 4-byte words for its five
+    EWMA rows (one per tau), card_mean and span_total (one per window),
+    3 CUSUM cells, the step, W# x R HLL registers and W# x D x K CMS
+    cells."""
+    t, nw = config.num_taus, config.num_windows
+    words = (
+        5 * t + 2 * nw + 3 + 1
+        + nw * (1 << config.hll_p) + nw * config.cms_depth * k
+    )
+    return 4 * words
 
 
 def _random_state(config: DetectorConfig, seed: int):
@@ -298,6 +310,19 @@ def test_gathered_evidence_builds_the_full_copy_bundle(n_keys, n_cands):
         json.dumps(b)
 
 
+def test_gather_reaches_the_device_under_its_own_name():
+    """The detector step is a bare partial and reaches the device as
+    ``jit__unknown``; a trace that reads the step by that name must not
+    count the gather, so the gather's program carries its own name."""
+    config = DetectorConfig(**SMALL)
+    det = AnomalyDetector(config)
+    lowered = det._evidence.lower(
+        det.state, np.int32(0), np.zeros((config.cms_depth, CAP), np.int32)
+    )
+    module = lowered.as_text().split("\n", 1)[0]
+    assert module.startswith("module @jit_evidence_gather "), module
+
+
 # --- live daemon ------------------------------------------------------
 
 
@@ -330,7 +355,7 @@ class TestLiveExplain:
             assert b["signals"] and set(b["heads"]) <= set(
                 HEAD_FOR_REASON.values()
             )
-            # Flag-time dispatch-lock snapshot landed: EWMA baselines,
+            # Flag-time evidence gather landed: EWMA baselines,
             # CUSUM accumulators vs thresholds, cardinality-vs-baseline.
             assert b["ewma"]["latency"]["mean"]
             assert len(b["cusum"]["thresholds"]) == 3
